@@ -26,7 +26,7 @@ def _true_rows(a, b):
         cap *= 2
     return np.asarray(esc.symbolic_exact(a.indptr, a.indices, b.indptr,
                                          b.indices, p_cap=cap,
-                                         num_rows_a=a.m, n_cols_b=b.n))
+                                         num_rows_a=a.m))
 
 
 def run(rows: list, scale: int = 1):

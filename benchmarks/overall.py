@@ -118,7 +118,7 @@ def run(rows: list, scale: int = 1):
                     jax.block_until_ready(kops.hash_bin_op(
                         hb.a_rows, a_vals, hb.a_starts, hb.a_lens,
                         b_cols_pad, b_vals_pad, table=hb.table,
-                        spill=hb.spill, n_cols=a.n, p_cap=hb.p_cap,
+                        spill=hb.spill, p_cap=hb.p_cap,
                         f_chunk=hb.f_chunk, tile=tile))
 
                 rung_call(hb.tile)  # compile outside the timed region

@@ -72,6 +72,8 @@ def main() -> None:
     from . import (ablation, common, cr_sampling, estimation_precision,
                    estimator_vs_cohen, graph, moe_dispatch, overall,
                    selection_validation, serving, sharding)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     modules = {
         "overall": overall,                       # Table 2 / Fig 6-7

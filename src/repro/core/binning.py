@@ -12,7 +12,8 @@ short rows (upper-bound workflow only, as in the paper), hash bins — the
 atomics-free probe-insert kernel in ``kernels.spgemm_hash`` — for
 mid-density rows whose output columns scatter far wider than their nnz,
 dense windows by range for the rest, and the column-tiled long-row kernel
-when a non-hash row's range exceeds the widest VMEM window.
+when a non-hash row's range exceeds the widest VMEM window — up to
+``LONGROW_MAX_TILES`` column tiles; wider long rows take the ESC bin.
 """
 from __future__ import annotations
 
@@ -31,6 +32,11 @@ WINDOW_LADDER = (256, 512, 1024, 2048, 4096)
 CAP_LADDER = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 # Column tile for the long-row kernel.
 LONGROW_TILE = 2048
+# Most column tiles a long row may take. The column-tiled kernel re-streams
+# the row's B rows once per tile and materializes a dense slab as wide as C,
+# so its work grows as products x tiles; past this many tiles the exact ESC
+# sort (work O(products), capacity = the product upper bound) takes the row.
+LONGROW_MAX_TILES = 8
 # Paper: smallest block size / ESC threshold.
 ESC_THRESHOLD = 64
 # Hash-accumulator rung (paper §3.3/§4.1): largest primary-table size the
@@ -208,15 +214,20 @@ def plan_bins(pred_nnz: np.ndarray, products: np.ndarray,
                         >= HASH_ADVANTAGE * table_of))
         dense_mask &= ~hash_mask
 
-    idx = np.nonzero(dense_mask)[0]
     max_w = WINDOW_LADDER[-1]
+    tiles_long = -(-n_cols // LONGROW_TILE)
+    if esc_enabled and tiles_long > LONGROW_MAX_TILES:
+        too_long = dense_mask & (np.asarray(width, np.int64) > max_w)
+        esc_mask |= too_long
+        dense_mask &= ~too_long
+
+    idx = np.nonzero(dense_mask)[0]
     # vectorized window assignment: every dense row gets a (window, tiles)
     # key; rows sharing a key share one kernel instantiation.
     w_idx = np.asarray(width, np.int64)[idx]
     cap_idx = np.minimum(caps[idx], max_w)
     window_of = round_up_ladder_vec(np.maximum(w_idx, cap_idx), WINDOW_LADDER)
     longrow = w_idx > max_w
-    tiles_long = int(np.ceil(n_cols / LONGROW_TILE)) if longrow.any() else 1
     window_of = np.where(longrow, LONGROW_TILE, window_of)
     tiles_of = np.where(longrow, tiles_long, 1)
 

@@ -2,9 +2,10 @@
 
 On TPU, sorting is a first-class XLA primitive, so ESC maps almost verbatim
 from the paper (§2.2/§3.3): expansion is a vectorized gather driven by a
-``cumsum``+``searchsorted`` product enumeration; sorting uses packed
-``row*n + col`` keys (int32 when they fit — the paper's key/ptr bit-packing
-insight, §4.2); compaction is a segmented sum.
+``cumsum``+``searchsorted`` product enumeration; sorting is one stable
+two-key ``lax.sort`` on (row, col) — no packed ``row*n + col`` key, so
+nothing wraps however wide C is while x64 stays disabled; compaction is
+a segmented sum.
 
 The same machinery with indices only implements the *exact symbolic pass*
 (the two-pass baseline Ocean replaces), and serves as the overflow-fallback
@@ -83,21 +84,16 @@ def expand(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
     return Expanded(rows, cols, vals, valid, total)
 
 
-def _pack_keys(rows, cols, n_cols: int, valid):
-    """Paper §4.2: pack (row, col) into the narrowest integer key that fits."""
-    rows64 = rows.astype(jnp.int64)
-    key = rows64 * jnp.int64(n_cols) + jnp.where(valid, cols, 0).astype(jnp.int64)
-    key = jnp.where(valid, key, jnp.iinfo(jnp.int64).max)
-    return key
-
-
-def pack_keys(rows, cols, n_cols: int, num_rows: int, valid):
-    """int32 keys when (num_rows+1) * n_cols fits in int31, else int64."""
-    if (num_rows + 1) * n_cols < 2**31:
-        key = rows.astype(jnp.int32) * jnp.int32(n_cols) + \
-            jnp.where(valid, cols, 0).astype(jnp.int32)
-        return jnp.where(valid, key, jnp.iinfo(jnp.int32).max)
-    return _pack_keys(rows, cols, n_cols, valid)
+def sort_by_row_col(rows, cols, *payload):
+    """Stable sort of products by (row, col); ``payload`` arrays ride
+    along. Invalid products carry a sentinel row past every real row, so
+    they sort last. Returns ``(rows, cols, *payload, head)`` where
+    ``head`` marks the first product of every (row, col) group."""
+    out = jax.lax.sort((rows, cols) + tuple(payload), num_keys=2)
+    rows_s, cols_s = out[0], out[1]
+    head = jnp.ones(rows_s.shape, bool).at[1:].set(
+        (rows_s[1:] != rows_s[:-1]) | (cols_s[1:] != cols_s[:-1]))
+    return tuple(out) + (head,)
 
 
 class ESCResult(NamedTuple):
@@ -107,34 +103,29 @@ class ESCResult(NamedTuple):
     nnz: jax.Array       # () int32 — true output nnz (may exceed out_cap!)
 
 
-@partial(jax.jit, static_argnames=("p_cap", "out_cap", "num_rows_a", "n_cols_b"))
+@partial(jax.jit, static_argnames=("p_cap", "out_cap", "num_rows_a"))
 def esc_spgemm(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
-               *, p_cap: int, out_cap: int, num_rows_a: int,
-               n_cols_b: int) -> ESCResult:
+               *, p_cap: int, out_cap: int, num_rows_a: int) -> ESCResult:
     """Full ESC SpGEMM. Caller checks ``nnz <= out_cap`` (overflow handling)."""
     ex = expand(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
                 p_cap=p_cap, num_rows_a=num_rows_a)
-    key = pack_keys(ex.rows, ex.cols, n_cols_b, num_rows_a, ex.valid)
-    key_s, val_s = jax.lax.sort((key, ex.vals), num_keys=1)
-    valid_s = key_s != jnp.iinfo(key_s.dtype).max
-
-    head = jnp.ones_like(valid_s)
-    head = head.at[1:].set(key_s[1:] != key_s[:-1])
+    rows_s, cols_s, val_s, head = sort_by_row_col(ex.rows, ex.cols, ex.vals)
+    valid_s = rows_s < num_rows_a
     head = head & valid_s
     seg = jnp.cumsum(head.astype(jnp.int32)) - 1          # compacted slot id
     nnz = jnp.sum(head.astype(jnp.int32))
 
     seg_cl = jnp.where(valid_s, jnp.clip(seg, 0, out_cap - 1), out_cap)
     out_vals = jax.ops.segment_sum(val_s, seg_cl, num_segments=out_cap + 1)[:-1]
-    # column index and row id of each compacted slot
-    key_of_slot = jax.ops.segment_max(
-        jnp.where(head, key_s, jnp.iinfo(key_s.dtype).min), seg_cl,
-        num_segments=out_cap + 1)[:-1]
+    # row id and column index of each compacted slot (one head per slot)
     slot_valid = jnp.arange(out_cap) < jnp.minimum(nnz, out_cap)
-    row_of_slot = jnp.where(
-        slot_valid, (key_of_slot // n_cols_b).astype(jnp.int32), num_rows_a)
-    col_of_slot = jnp.where(
-        slot_valid, (key_of_slot % n_cols_b).astype(jnp.int32), PAD_COL)
+    first_of = jnp.where(head, seg_cl, out_cap)
+    row_of_slot = jnp.full((out_cap + 1,), num_rows_a, jnp.int32).at[
+        first_of].set(rows_s)[:-1]
+    col_of_slot = jnp.full((out_cap + 1,), PAD_COL, jnp.int32).at[
+        first_of].set(cols_s)[:-1]
+    row_of_slot = jnp.where(slot_valid, row_of_slot, num_rows_a)
+    col_of_slot = jnp.where(slot_valid, col_of_slot, PAD_COL)
     out_vals = jnp.where(slot_valid, out_vals, 0)
 
     counts = jax.ops.segment_sum(
@@ -145,9 +136,9 @@ def esc_spgemm(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
     return ESCResult(indptr, col_of_slot, out_vals, nnz)
 
 
-@partial(jax.jit, static_argnames=("p_cap", "num_rows_a", "n_cols_b"))
+@partial(jax.jit, static_argnames=("p_cap", "num_rows_a"))
 def symbolic_exact(a_indptr, a_indices, b_indptr, b_indices,
-                   *, p_cap: int, num_rows_a: int, n_cols_b: int) -> jax.Array:
+                   *, p_cap: int, num_rows_a: int) -> jax.Array:
     """Exact per-row output nnz — the classical symbolic pass (indices only).
 
     This is the step Ocean's HLL estimation replaces; it remains both the
@@ -155,15 +146,9 @@ def symbolic_exact(a_indptr, a_indices, b_indptr, b_indices,
     """
     ex = expand(a_indptr, a_indices, None, b_indptr, b_indices, None,
                 p_cap=p_cap, num_rows_a=num_rows_a, with_values=False)
-    key = pack_keys(ex.rows, ex.cols, n_cols_b, num_rows_a, ex.valid)
-    key_s = jax.lax.sort(key)
-    valid_s = key_s != jnp.iinfo(key_s.dtype).max
-    head = jnp.ones_like(valid_s)
-    head = head.at[1:].set(key_s[1:] != key_s[:-1])
-    head = head & valid_s
-    row_s = (key_s // n_cols_b).astype(jnp.int32)
-    row_s = jnp.where(valid_s, row_s, num_rows_a)
-    counts = jax.ops.segment_sum(head.astype(jnp.int32), row_s,
+    rows_s, _, head = sort_by_row_col(ex.rows, ex.cols)
+    head = head & (rows_s < num_rows_a)
+    counts = jax.ops.segment_sum(head.astype(jnp.int32), rows_s,
                                  num_segments=num_rows_a + 1)[:-1]
     return counts
 
@@ -172,7 +157,7 @@ def symbolic_exact_host(a_indptr, a_indices, b_indptr, b_indices,
                         *, num_rows_a: int, n_cols_b: int) -> np.ndarray:
     """Host (numpy) twin of :func:`symbolic_exact` — bit-identical counts.
 
-    Same expand -> packed-key sort -> unique-head compaction, but over
+    Same expand -> (row, col) sort -> unique-head compaction, but over
     int64 numpy arrays with no device round trip or jit specialization.
     On the CPU backend the planner's symbolic prediction takes this path:
     the XLA version pays a device dispatch plus a pow2-padded sort

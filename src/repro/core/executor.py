@@ -248,7 +248,7 @@ def _run_dense_bin(be: DenseBinExec, a_values: np.ndarray, b_cols_pad,
 
 
 def _run_hash_bin(hb: HashBinExec, a_values: np.ndarray, b_cols_pad,
-                  b_vals_pad, n_cols: int):
+                  b_vals_pad):
     """Dispatch one hash bin; returns device arrays (cols, vals, nnz).
 
     Same per-row-independence contract as dense bins: each row owns its
@@ -260,7 +260,7 @@ def _run_hash_bin(hb: HashBinExec, a_values: np.ndarray, b_cols_pad,
     a_vals = _gather_ell_values(hb, a_values)
     return kops.hash_bin_op(
         hb.a_rows, a_vals, hb.a_starts, hb.a_lens, b_cols_pad, b_vals_pad,
-        table=hb.table, spill=hb.spill, n_cols=n_cols, p_cap=hb.p_cap,
+        table=hb.table, spill=hb.spill, p_cap=hb.p_cap,
         f_chunk=hb.f_chunk, tile=hb.tile)
 
 
@@ -280,8 +280,7 @@ def _run_esc_bin(ex: EscExec, a_values: np.ndarray, b: CSR, *,
     return esc_mod.esc_spgemm(
         ex.sub_indptr, ex.sub_indices, a_values[ex.src],
         b_indptr, b_indices, b_values, p_cap=ex.p_cap,
-        out_cap=ex.out_cap, num_rows_a=ex.sub_indptr.shape[0] - 1,
-        n_cols_b=b.n)
+        out_cap=ex.out_cap, num_rows_a=ex.sub_indptr.shape[0] - 1)
 
 
 def _compact_slabs(slabs: List[_Slab], shape: Tuple[int, int],
@@ -353,8 +352,7 @@ def _dispatch(shards: List[_ShardWork], a_values: np.ndarray,
                 items.append(Launch(("dense", be), order, tuple(arrays)))
                 order += 1
             for hb in shard.hash:
-                arrays = _run_hash_bin(hb, a_values, b_cols_pad, b_vals_pad,
-                                       b.n)
+                arrays = _run_hash_bin(hb, a_values, b_cols_pad, b_vals_pad)
                 items.append(Launch(("hash", hb), order, tuple(arrays)))
                 order += 1
             if shard.esc is not None:
@@ -496,8 +494,7 @@ def _run_overflow_fallback(state: _MergeState, products: np.ndarray,
         p_cap = pow2_at_least(int(products[rows].sum()), floor=64)
         res = esc_mod.esc_spgemm(
             sub.indptr, sub.indices, sub.values, b.indptr, b.indices,
-            b.values, p_cap=p_cap, out_cap=p_cap, num_rows_a=sub.m,
-            n_cols_b=b.n)
+            b.values, p_cap=p_cap, out_cap=p_cap, num_rows_a=sub.m)
         slab, _ = _esc_to_slab(res, rows, sub.m, p_cap)
         state.add_fallback(slab)
         sp.set(rows=len(rows))

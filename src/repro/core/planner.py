@@ -469,7 +469,7 @@ def build_plan(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
             pred = np.asarray(
                 esc_mod.symbolic_exact(a.indptr, a.indices, b.indptr,
                                        b.indices, p_cap=p_cap,
-                                       num_rows_a=a.m, n_cols_b=b.n),
+                                       num_rows_a=a.m),
                 np.float64)
     else:  # upper_bound
         pred = products.astype(np.float64)

@@ -22,8 +22,9 @@ component keeps those measurements from aliasing Pallas-path ones.
 
 Winners cache in a :class:`TuningCache` — a thread-safe LRU keyed by a
 digest of (rung, backend, kernel path), the same keying discipline as
-``planner.PlanCache``. Measurement failures (e.g. an exotic backend) fall
-back to the untuned defaults, so tuning can never break a build.
+``planner.PlanCache``. A measurement that fails raises, so a kernel that
+cannot run on this backend surfaces at plan build instead of being masked
+by untuned defaults.
 """
 from __future__ import annotations
 
@@ -42,8 +43,8 @@ from .formats import pow2_at_least
 
 # Candidate grid. Load factors below 0.5 waste VMEM; above ~0.85 linear
 # probing degrades. f_chunk=64 only matters on the Pallas path (smaller
-# DMA granularity for short B rows), as does the row tile (tile_rows=1 is
-# the row-sequential degeneracy; 8 matches the f32 sublane tile). The
+# DMA granularity for short B rows), as does the row tile (a multiple of
+# the f32 sublane tile 8, the only tiles the TPU lowering accepts). The
 # tile ladder descends from the widest candidate: per-step work shrinks
 # monotonically down the ladder, so once a step times *worse* than its
 # predecessor the rest of the tail can only lose and the sweep prunes it
@@ -52,7 +53,7 @@ LOAD_FACTOR_CANDIDATES = (0.5, HASH_LOAD_FACTOR)
 F_CHUNK_CANDIDATES = (128,)
 F_CHUNK_CANDIDATES_PALLAS = (128, 64)
 TILE_CANDIDATES = (8,)
-TILE_CANDIDATES_PALLAS = (8, 4, 2, 1)
+TILE_CANDIDATES_PALLAS = (16, 8)
 
 # The rung the planner consults for the load factor it hands to binning
 # (binning runs before per-bin rungs are known, so one representative
@@ -194,7 +195,7 @@ def _measure(rung: int) -> HashTuning:
                 def run():
                     out = kops.hash_bin_op(
                         *work, table=table, spill=hash_spill_of(table),
-                        n_cols=max(2 * rung, 64), p_cap=p_cap, f_chunk=fc,
+                        p_cap=p_cap, f_chunk=fc,
                         tile=tr)
                     jax.block_until_ready(out[0])
 
@@ -230,16 +231,13 @@ def hash_tuning_for(rung: int,
                     cache: Optional[TuningCache] = None) -> HashTuning:
     """Measured (load_factor, f_chunk, tile_rows) for a rung, cached.
 
-    Never raises: measurement errors return the untuned defaults (and
-    cache them, so a broken backend is probed once, not per plan)."""
+    A measurement that fails raises: a kernel that cannot run here must
+    not hide behind untuned defaults, and nothing is cached for it."""
     cache = DEFAULT_TUNING_CACHE if cache is None else cache
     key = tuning_key(rung)
     hit = cache.lookup(key)
     if hit is not None:
         return hit
-    try:
-        tuned = _measure(int(rung))
-    except Exception:
-        tuned = DEFAULT_TUNING
+    tuned = _measure(int(rung))
     cache.insert(key, tuned)
     return tuned

@@ -26,6 +26,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.obs import trace
 from . import esc as esc_mod
@@ -38,7 +39,7 @@ from .planner import (DEFAULT_PLAN_CACHE, ExecutionPlan, OceanReport,
                       execute_sharded_plan, gather_rows, structure_key)
 
 __all__ = ["OceanReport", "ocean_spgemm", "ocean_spgemm_many",
-           "spgemm_reference", "gather_rows", "warm_plan"]
+           "spgemm_reference", "scipy_mismatch", "gather_rows", "warm_plan"]
 
 
 def _resolve_cache(cache: Union[bool, PlanCache, None]):
@@ -297,6 +298,49 @@ def ocean_spgemm_many(a_list: Sequence[CSR], b: CSR,
             for a, c, s in zip(a_list, caches, sketches)]
 
 
+def scipy_mismatch(c: CSR, a: CSR, b: CSR) -> Optional[str]:
+    """Check ``C == A @ B`` against scipy, an implementation independent of
+    this package. Returns ``None`` on a match, else what differs.
+
+    The structure must match exactly. scipy drops exact zeros from a value
+    product, so the structure comes from the pattern product (all values
+    1), which also counts the products each entry sums. Values must lie
+    within the forward error bound of f32 summation: ``count * 2**-23 *
+    (|A| @ |B|)`` entrywise, with scipy's float64 product as the truth.
+    """
+    import scipy.sparse as sp
+
+    def mat(x: CSR, data=None) -> "sp.csr_matrix":
+        ip, ii, vv = x.to_scipy_like()
+        vals = vv.astype(np.float64) if data is None else data(vv)
+        return sp.csr_matrix((vals, ii, ip), shape=x.shape)
+
+    ones = lambda v: np.ones(v.shape, np.float64)  # noqa: E731
+    count = mat(a, ones) @ mat(b, ones)
+    count.sort_indices()
+    ip, ii, vv = c.to_scipy_like()
+    if c.shape != count.shape:
+        return f"shape {c.shape} != {count.shape}"
+    if not (np.array_equal(ip, count.indptr)
+            and np.array_equal(ii, count.indices)):
+        return (f"structure differs: nnz {c.nnz} vs {count.nnz}, "
+                f"{int(np.sum(np.diff(ip) != np.diff(count.indptr)))} rows "
+                "with a different entry count")
+    rows = np.repeat(np.arange(c.m), np.diff(count.indptr))
+    exact = np.asarray((mat(a) @ mat(b))[rows, count.indices]).ravel()
+    mag = np.asarray((mat(a, np.abs) @ mat(b, np.abs))[rows, count.indices]
+                     ).ravel()
+    bound = count.data * 2.0**-23 * mag
+    err = np.abs(vv.astype(np.float64) - exact)
+    bad = err > bound
+    if bad.any():
+        i = int(np.argmax(err - bound))
+        return (f"{int(bad.sum())} values outside the f32 bound; worst at "
+                f"({int(rows[i])}, {int(count.indices[i])}): {vv[i]} vs "
+                f"{exact[i]} (bound {bound[i]})")
+    return None
+
+
 def spgemm_reference(a: CSR, b: CSR) -> CSR:
     """Exact two-pass reference via the ESC machinery (used as oracle)."""
     from .analysis import products_per_row
@@ -305,5 +349,5 @@ def spgemm_reference(a: CSR, b: CSR) -> CSR:
     p_cap = pow2_at_least(p + 1, floor=64)
     res = esc_mod.esc_spgemm(a.indptr, a.indices, a.values, b.indptr,
                              b.indices, b.values, p_cap=p_cap, out_cap=p_cap,
-                             num_rows_a=a.m, n_cols_b=b.n)
+                             num_rows_a=a.m)
     return esc_mod.esc_to_csr(res, (a.m, b.n), p_cap)

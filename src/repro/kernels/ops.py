@@ -1,9 +1,9 @@
 """jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) kernels execute with ``interpret=True``, which runs
+On the CPU backend kernels execute with ``interpret=True``, which runs
 the kernel body as traced JAX ops — bit-accurate against the TPU lowering
 for these integer/float ops. On TPU backends the same calls compile via
-Mosaic. ``REPRO_FORCE_INTERPRET=0/1`` overrides the auto-detection.
+Mosaic.
 """
 from __future__ import annotations
 
@@ -26,9 +26,7 @@ F_CHUNK = kdense.F_CHUNK
 
 
 def use_interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
+    """Pallas kernels run interpreted exactly when the backend is the CPU."""
     return jax.default_backend() == "cpu"
 
 
@@ -79,14 +77,7 @@ def merge_estimate_op(a: CSR, sketches_with_sentinel: jax.Array,
                                      num_rows_a=a.m)
         est = chll.estimate_cardinality(merged, clip_max=clip_max)
         return merged, est
-    nb1 = sketches_with_sentinel.shape[0]
-    max_len = int(jnp.max(a.indptr[1:] - a.indptr[:-1]))
-    k = max(max_len, 1)
-    ell, _ = csr_rows_to_ell(a.indptr, a.indices, None, num_rows=a.m,
-                             ell_width=k, pad_index=nb1 - 1)
-    # clamp any stray index (safety) to the sentinel row
-    ell = jnp.where((ell < 0) | (ell >= nb1), nb1 - 1, ell)
-    merged, est = khll.hll_merge(ell, sketches_with_sentinel,
+    merged, est = khll.hll_merge(a.indptr, a.indices, sketches_with_sentinel,
                                  interpret=use_interpret())
     if clip_max is not None:
         est = jnp.clip(est, 0.0, float(clip_max))
@@ -172,9 +163,7 @@ def dense_bin_op(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols_pad,
     bin-level capacity so they share a single jit specialization instead
     of compiling per shard-local product sum.
     """
-    use_pallas = (not use_interpret()
-                  or os.environ.get("REPRO_CPU_NUMERIC") == "pallas")
-    if use_pallas:
+    if _use_pallas_path():
         acc, cnt = kdense.spgemm_dense_bin(
             a_rows, a_vals, a_starts, a_lens, row_lo, b_cols_pad, b_vals_pad,
             window=window, col_tiles=col_tiles, interpret=use_interpret())
@@ -221,12 +210,12 @@ def extract_hash_rows(keys, vals, skeys, svals, fail):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("table", "spill", "n_cols", "p_cap"))
+                   static_argnames=("table", "spill", "p_cap"))
 def _hash_bin_xla(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
-                  *, table: int, spill: int, n_cols: int, p_cap: int):
+                  *, table: int, spill: int, p_cap: int):
     """Vectorized XLA executor for a hash bin — identical slab semantics to
     the Pallas kernel + ``extract_hash_rows``. Enumerates all products
-    (same scheme as ``_dense_bin_xla``), sorts by packed (row, col) key and
+    (same scheme as ``_dense_bin_xla``), sorts by (row, col) and
     segment-sums duplicates; per-(row, col) accumulation order equals the
     kernel's insertion order (product enumeration order), and the exact
     per-row distinct count crosses ``table + spill`` exactly when the
@@ -250,18 +239,14 @@ def _hash_bin_xla(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
     # sort products by (row, col); stable sort keeps enumeration order
     # within a (row, col) group, so the segment sums accumulate in the
     # same order the hash kernel's sequential inserts do
-    from repro.core.esc import pack_keys
-    key = pack_keys(jnp.where(ok, row, r), col, n_cols, r, ok)
-    key_s, val_s = jax.lax.sort((key, val), dimension=0, num_keys=1)
-    valid_s = key_s != jnp.iinfo(key_s.dtype).max
-    head = jnp.ones_like(valid_s)
-    head = head.at[1:].set(key_s[1:] != key_s[:-1])
+    from repro.core.esc import sort_by_row_col
+    row_d, col_d, val_s, head = sort_by_row_col(jnp.where(ok, row, r), col,
+                                                val)
+    valid_s = row_d < r
     seg = jnp.cumsum(head.astype(jnp.int32)) - 1
     sums = jax.ops.segment_sum(jnp.where(valid_s, val_s, 0), seg,
                                num_segments=p_cap)
     take = head & valid_s
-    row_d = (key_s // n_cols).astype(jnp.int32)
-    col_d = (key_s % n_cols).astype(jnp.int32)
     rowseg = jnp.where(take, row_d, r)
     counts = jax.ops.segment_sum(take.astype(jnp.int32), rowseg,
                                  num_segments=r + 1)[:r]
@@ -281,7 +266,7 @@ def _hash_bin_xla(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
 
 
 def hash_bin_op(a_rows, a_vals, a_starts, a_lens, b_cols_pad, b_vals_pad,
-                *, table: int, spill: int, n_cols: int,
+                *, table: int, spill: int,
                 p_cap: int | None = None, f_chunk: int = F_CHUNK,
                 tile: int = khash.DEFAULT_TILE_ROWS):
     """Run one bin through the hash-accumulator kernel and compact it.
@@ -307,7 +292,7 @@ def hash_bin_op(a_rows, a_vals, a_starts, a_lens, b_cols_pad, b_vals_pad,
         p_cap = pow2_at_least(int(jnp.sum(a_lens)), floor=64)
     return _hash_bin_xla(
         a_rows, a_vals, a_starts, a_lens, b_cols_pad, b_vals_pad,
-        table=table, spill=spill, n_cols=n_cols, p_cap=p_cap)
+        table=table, spill=spill, p_cap=p_cap)
 
 
 def prep_bin_structure(a: CSR, b: CSR, rows: np.ndarray, ell_width: int):

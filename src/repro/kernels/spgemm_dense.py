@@ -8,11 +8,21 @@ TPU adaptation of the paper's accumulation kernels (§3.3):
   the MXU: ``acc += vals(1,F) @ onehot(F,W)``. Presence (the paper's dense
   bitmap) accumulates the same way from the validity mask, which preserves
   the *structural* nnz semantics the symbolic pass would have produced.
+  The matmul runs at ``Precision.HIGHEST``: each output column receives at
+  most one product per chunk (a B row holds each column once), so the
+  one-hot product reproduces every f32 value exactly and the per-column
+  sums accumulate in product-enumeration order, like the XLA twin.
 
 * The enhanced hash accumulator's shared/global split (hot index structure
   on-chip, cold values off-chip) maps to the VMEM/HBM hierarchy: the active
   accumulator window and the B-row chunk live in VMEM; the B nonzero stream
   and the output slab stay in HBM and are moved by explicit async DMA.
+  A-side scalars (B-row starts, lengths, A values) sit in SMEM, where the
+  scalar core reads them at dynamic indices.
+
+* B's flat nonzero arrays are streamed as lane-dense ``(N, 128)`` blocks
+  (:func:`b_blocks`): a DMA moves whole 128-lane block rows, and a chunk
+  masks the positions that belong to the B row it serves.
 
 * Long rows (window > VMEM budget) run the same kernel with a column-tile
   grid dimension: each tile re-streams the row's B rows and accumulates only
@@ -20,9 +30,11 @@ TPU adaptation of the paper's accumulation kernels (§3.3):
   trade the paper's global-memory fallback makes (its §5.4 ``torso1``
   pathology corresponds exactly to a high re-stream factor here).
 
-Grid: ``(rows, col_tiles)``; col_tiles == 1 for windowed (binned) rows.
-Each program owns one ``(1, W)`` output block: no cross-program races, which
-is what the per-row binning guarantees on GPU too.
+Grid: ``(rows / ROW_TILE, col_tiles)``; col_tiles == 1 for windowed
+(binned) rows. Each program owns one ``(ROW_TILE, W)`` output block and
+accumulates its rows one after another: no cross-program races, which is
+what the per-row binning guarantees on GPU too. Rows are padded to a
+``ROW_TILE`` multiple with inert rows inside the wrappers.
 """
 from __future__ import annotations
 
@@ -33,114 +45,141 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# B-row nonzeros are streamed through VMEM in chunks of F_CHUNK; 128 matches
-# the MXU contraction dimension.
+# B nonzeros move in lane-dense blocks of F_CHUNK; 128 is the lane width
+# and the MXU contraction dimension.
 F_CHUNK = 128
+# Output rows per grid step: the f32 sublane tile, the smallest row block
+# the TPU lowering accepts over an R-row array.
+ROW_TILE = 8
 
 
-def _dense_kernel(a_rows_ref, a_vals_ref, a_starts_ref, a_lens_ref,
-                  row_lo_ref, b_cols_hbm, b_vals_hbm,
-                  acc_ref, cnt_ref,
-                  bcol_scratch, bval_scratch, sem_c, sem_v,
-                  *, window: int, f_chunk: int):
-    t = pl.program_id(1)
-    lo = row_lo_ref[0, 0] + t * window
-
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    e_total = a_rows_ref.shape[1]
-    nnz_pad = b_cols_hbm.shape[0]
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (f_chunk, window), 1)
-
-    def e_body(e, _):
-        k = a_rows_ref[0, e]
-        av = a_vals_ref[0, e]
-        active = k >= 0
-        start = a_starts_ref[0, e]
-        length = jnp.where(active, a_lens_ref[0, e], 0)
-        n_chunks = pl.cdiv(length, f_chunk)
-
-        def c_body(c, _):
-            src = jnp.clip(start + c * f_chunk, 0, nnz_pad - f_chunk)
-            cp_c = pltpu.make_async_copy(
-                b_cols_hbm.at[pl.ds(src, f_chunk)], bcol_scratch, sem_c)
-            cp_v = pltpu.make_async_copy(
-                b_vals_hbm.at[pl.ds(src, f_chunk)], bval_scratch, sem_v)
-            cp_c.start()
-            cp_v.start()
-            cp_c.wait()
-            cp_v.wait()
-            # chunk may start below `start` after the clip; recompute offsets
-            pos = jax.lax.broadcasted_iota(jnp.int32, (1, f_chunk), 1) + src
-            in_row = (pos >= start) & (pos < start + length)
-            cols = bcol_scratch[...].reshape(1, f_chunk)
-            cols_local = cols - lo
-            ok = in_row & (cols_local >= 0) & (cols_local < window)
-            onehot = (jnp.where(ok, cols_local, -1).reshape(f_chunk, 1)
-                      == col_iota)
-            vals = jnp.where(ok, av * bval_scratch[...].reshape(1, f_chunk), 0)
-            acc_ref[...] += jax.lax.dot_general(
-                vals, onehot.astype(vals.dtype),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=acc_ref.dtype)
-            ones = jnp.where(ok, 1.0, 0.0).astype(jnp.float32)
-            cnt_ref[...] += jax.lax.dot_general(
-                ones, onehot.astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return 0
-
-        jax.lax.fori_loop(0, n_chunks, c_body, 0)
-        return 0
-
-    jax.lax.fori_loop(0, e_total, e_body, 0)
+def b_blocks(flat: jax.Array, fill) -> jax.Array:
+    """Flat B array -> ``(N, F_CHUNK)`` blocks with at least one spare
+    block at the end, so a chunk's two-block window never reads past it."""
+    n_blk = flat.shape[0] // F_CHUNK + 2
+    flat = jnp.pad(flat, (0, n_blk * F_CHUNK - flat.shape[0]),
+                   constant_values=fill)
+    return flat.reshape(n_blk, F_CHUNK)
 
 
-def _count_kernel(a_rows_ref, a_starts_ref, a_lens_ref, row_lo_ref,
-                  b_cols_hbm, cnt_ref, bcol_scratch, sem_c,
-                  *, window: int, f_chunk: int):
-    """Symbolic (count-only) variant: no value DMA, no value matmul — the
-    TPU analogue of the paper's cheaper symbolic accumulation (§2.3:
+def pad_rows(x: jax.Array, rows: int, fill=0) -> jax.Array:
+    """Pad the leading (row) axis of ``x`` to ``rows`` with ``fill``."""
+    if x.shape[0] == rows:
+        return x
+    pad = [(0, rows - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    return jnp.pad(x, pad, constant_values=fill)
+
+
+def _accumulate_kernel(*refs, window: int, with_values: bool):
+    """Dense window accumulation of ROW_TILE rows; ``with_values=False``
+    is the symbolic (count-only) variant: no value DMA, no value matmul —
+    the TPU analogue of the paper's cheaper symbolic accumulation (§2.3:
     'numerical values are discarded')."""
-    t = pl.program_id(1)
-    lo = row_lo_ref[0, 0] + t * window
+    if with_values:
+        (a_vals_ref, a_starts_ref, a_lens_ref, row_lo_ref, b_cols_hbm,
+         b_vals_hbm, acc_ref, cnt_ref, bcol_buf, bval_buf, sem) = refs
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+    else:
+        (a_starts_ref, a_lens_ref, row_lo_ref, b_cols_hbm, cnt_ref,
+         bcol_buf, sem) = refs
     cnt_ref[...] = jnp.zeros_like(cnt_ref)
-    e_total = a_rows_ref.shape[1]
-    nnz_pad = b_cols_hbm.shape[0]
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (f_chunk, window), 1)
+    t = pl.program_id(1)
+    e_total = a_starts_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, F_CHUNK), 1)
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (window, F_CHUNK), 0)
+    # vals (1,F) . onehot(W,F)^T -> (1,W)
+    nt = (((1,), (1,)), ((), ()))
+    exact = jax.lax.Precision.HIGHEST
 
-    def e_body(e, _):
-        k = a_rows_ref[0, e]
-        active = k >= 0
-        start = a_starts_ref[0, e]
-        length = jnp.where(active, a_lens_ref[0, e], 0)
-        n_chunks = pl.cdiv(length, f_chunk)
+    def row_body(r, _):
+        lo = row_lo_ref[r, 0] + t * window
 
-        def c_body(c, _):
-            src = jnp.clip(start + c * f_chunk, 0, nnz_pad - f_chunk)
-            cp_c = pltpu.make_async_copy(
-                b_cols_hbm.at[pl.ds(src, f_chunk)], bcol_scratch, sem_c)
-            cp_c.start()
-            cp_c.wait()
-            pos = jax.lax.broadcasted_iota(jnp.int32, (1, f_chunk), 1) + src
-            in_row = (pos >= start) & (pos < start + length)
-            cols = bcol_scratch[...].reshape(1, f_chunk)
-            cols_local = cols - lo
-            ok = in_row & (cols_local >= 0) & (cols_local < window)
-            onehot = (jnp.where(ok, cols_local, -1).reshape(f_chunk, 1)
-                      == col_iota)
-            ones = jnp.where(ok, 1.0, 0.0).astype(jnp.float32)
-            cnt_ref[...] += jax.lax.dot_general(
-                ones, onehot.astype(jnp.float32),
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        def e_body(e, _):
+            start = a_starts_ref[r, e]
+            length = a_lens_ref[r, e]
+            first = start // F_CHUNK
+            last = (start + jnp.maximum(length, 1) - 1) // F_CHUNK
+            n_blk = jnp.where(length > 0, last - first + 1, 0)
+
+            def blk_body(j, _):
+                blk = first + j
+                cp_c = pltpu.make_async_copy(
+                    b_cols_hbm.at[pl.ds(blk, 1), :], bcol_buf, sem.at[0])
+                cp_c.start()
+                if with_values:
+                    cp_v = pltpu.make_async_copy(
+                        b_vals_hbm.at[pl.ds(blk, 1), :], bval_buf, sem.at[1])
+                    cp_v.start()
+                    cp_v.wait()
+                cp_c.wait()
+                pos = blk * F_CHUNK + lane
+                cols_local = bcol_buf[...] - lo
+                ok = ((pos >= start) & (pos < start + length)
+                      & (cols_local >= 0) & (cols_local < window))
+                onehot = (col_iota == jnp.where(ok, cols_local, -1)
+                          ).astype(jnp.float32)
+                cnt_ref[pl.ds(r, 1), :] += jax.lax.dot_general(
+                    ok.astype(jnp.float32), onehot, nt, precision=exact,
+                    preferred_element_type=jnp.float32)
+                if with_values:
+                    vals = jnp.where(ok, a_vals_ref[r, e] * bval_buf[...], 0)
+                    acc_ref[pl.ds(r, 1), :] += jax.lax.dot_general(
+                        vals, onehot.astype(vals.dtype), nt, precision=exact,
+                        preferred_element_type=acc_ref.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, n_blk, blk_body, 0)
             return 0
 
-        jax.lax.fori_loop(0, n_chunks, c_body, 0)
+        jax.lax.fori_loop(0, e_total, e_body, 0)
         return 0
 
-    jax.lax.fori_loop(0, e_total, e_body, 0)
+    jax.lax.fori_loop(0, ROW_TILE, row_body, 0)
+
+
+def _bin_call(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols, b_vals, *,
+              window: int, col_tiles: int, interpret: bool):
+    """Shared pallas_call of the dense and count kernels (``a_vals`` /
+    ``b_vals`` None selects the count-only variant)."""
+    r, e = a_rows.shape
+    r_pad = pl.cdiv(max(r, 1), ROW_TILE) * ROW_TILE
+    out_w = col_tiles * window
+    with_values = a_vals is not None
+    # inactive ELL slots (a_rows < 0) stream nothing
+    a_lens = pad_rows(jnp.where(a_rows >= 0, a_lens, 0), r_pad)
+    a_starts = pad_rows(a_starts, r_pad)
+    row_lo = pad_rows(row_lo, r_pad)
+    smem_block = pl.BlockSpec((ROW_TILE, e), lambda i, t: (i, 0),
+                              memory_space=pltpu.SMEM)
+    in_specs = [smem_block, smem_block,
+                pl.BlockSpec((ROW_TILE, 1), lambda i, t: (i, 0),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    args = [a_starts, a_lens, row_lo, b_blocks(b_cols, -1)]
+    out_block = pl.BlockSpec((ROW_TILE, window), lambda i, t: (i, t))
+    out_specs = [out_block]
+    out_shape = [jax.ShapeDtypeStruct((r_pad, out_w), jnp.float32)]
+    scratch = [pltpu.VMEM((1, F_CHUNK), jnp.int32)]
+    if with_values:
+        in_specs = [smem_block] + in_specs + [
+            pl.BlockSpec(memory_space=pl.ANY)]
+        args = ([pad_rows(a_vals, r_pad)] + args
+                + [b_blocks(b_vals, 0)])
+        out_specs = [out_block, out_block]
+        out_shape = [jax.ShapeDtypeStruct((r_pad, out_w), b_vals.dtype)
+                     ] + out_shape
+        scratch.append(pltpu.VMEM((1, F_CHUNK), b_vals.dtype))
+    out = pl.pallas_call(
+        functools.partial(_accumulate_kernel, window=window,
+                          with_values=with_values),
+        grid=(r_pad // ROW_TILE, col_tiles),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
+        interpret=interpret,
+    )(*args)
+    return [x[:r] for x in out]
 
 
 @functools.partial(jax.jit,
@@ -150,27 +189,10 @@ def spgemm_count_bin(a_rows, a_starts, a_lens, row_lo, b_cols,
                      interpret: bool = False):
     """Count-only (symbolic) pass over one bin: returns counts
     (R, col_tiles*window) f32; exact per-row nnz = sum(counts > 0)."""
-    r, e = a_rows.shape
-    out_w = col_tiles * window
-    kernel = functools.partial(_count_kernel, window=window, f_chunk=F_CHUNK)
-    return pl.pallas_call(
-        kernel,
-        grid=(r, col_tiles),
-        in_specs=[
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, window), lambda i, t: (i, t)),
-        out_shape=jax.ShapeDtypeStruct((r, out_w), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((F_CHUNK,), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(a_rows, a_starts, a_lens, row_lo, b_cols)
+    (cnt,) = _bin_call(a_rows, None, a_starts, a_lens, row_lo, b_cols, None,
+                       window=window, col_tiles=col_tiles,
+                       interpret=interpret)
+    return cnt
 
 
 @functools.partial(jax.jit,
@@ -185,42 +207,13 @@ def spgemm_dense_bin(a_rows, a_vals, a_starts, a_lens, row_lo,
     a_starts: (R, E) int32 — b_indptr[k] pregathered (pad = 0)
     a_lens:   (R, E) int32 — B-row lengths (pad = 0)
     row_lo:   (R, 1) int32 — dense-window base column per row
-    b_cols:   (nnzB_pad,) int32 — flat B column indices (HBM), padded by
-              >= F_CHUNK
+    b_cols:   (nnzB_pad,) int32 — flat B column indices (HBM)
     b_vals:   (nnzB_pad,) float
+    window:   a multiple of 128 (lane-dense output blocks)
     Returns (acc (R, col_tiles*window) float, counts (R, col_tiles*window)
     f32); presence = counts > 0.
     """
-    r, e = a_rows.shape
-    out_w = col_tiles * window
-    dtype = b_vals.dtype
-    kernel = functools.partial(_dense_kernel, window=window, f_chunk=F_CHUNK)
-    acc, cnt = pl.pallas_call(
-        kernel,
-        grid=(r, col_tiles),
-        in_specs=[
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, e), lambda i, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, t: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, window), lambda i, t: (i, t)),
-            pl.BlockSpec((1, window), lambda i, t: (i, t)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, out_w), dtype),
-            jax.ShapeDtypeStruct((r, out_w), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((F_CHUNK,), jnp.int32),
-            pltpu.VMEM((F_CHUNK,), dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols, b_vals)
+    acc, cnt = _bin_call(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols,
+                         b_vals, window=window, col_tiles=col_tiles,
+                         interpret=interpret)
     return acc, cnt

@@ -23,7 +23,7 @@ memory split:
 GPU hash accumulators insert with atomicCAS loops; TPU has no atomics, so
 one probe-insert is reformulated as a whole-table vector op: compare every
 slot against the key (hit detection), compute each empty slot's probe
-distance from the home slot, pick ``argmin`` as the insertion point, and
+distance from the home slot, pick the nearest as the insertion point, and
 commit the write through a one-hot mask. Insertion order within a row is
 the product enumeration order (A-slot major, B-position minor), matching
 the XLA fallback's segment accumulation order bit for bit.
@@ -38,7 +38,13 @@ so any tile size produces bit-identical per-row output (``tile=1``
 degenerates to the original row-sequential kernel; pinned in
 ``tests/test_hash.py``). Rows are padded to a tile multiple with inert
 rows (no A entries) inside :func:`spgemm_hash_bin`, so callers never see
-the tiling.
+the tiling. Compiled for a TPU, the tile must be a multiple of 8 (the
+sublane tile); other tiles run only in interpret mode.
+
+A-side scalars (A values, B-row starts and lengths) sit in SMEM; each
+step assembles the tile's (T, 1) lanes from them. B streams as
+lane-dense ``(N, 128)`` blocks: a row's chunk lies in two consecutive
+blocks, and each step picks its element out of them by position.
 """
 from __future__ import annotations
 
@@ -49,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .spgemm_dense import F_CHUNK
+from .spgemm_dense import F_CHUNK, b_blocks, pad_rows
 
 # Knuth's multiplicative (Fibonacci) hash constant: 2**32 / phi.
 _FIB_MULT = 2654435769
@@ -76,17 +82,15 @@ def _probe_insert(keys_ref, vals_ref, col, v, use, size: int):
     iota = jax.lax.broadcasted_iota(jnp.int32, (t, size), 1)
     h = (jnp.maximum(col, 0).astype(jnp.uint32) * jnp.uint32(_FIB_MULT)
          >> jnp.uint32(32 - p)).astype(jnp.int32)      # (T, 1)
-    is_col = keys == col
-    found = jnp.any(is_col, axis=1, keepdims=True)     # (T, 1)
+    # slot holding the key (size when absent); keys are unique per table
+    hit = jnp.min(jnp.where(keys == col, iota, size), axis=1, keepdims=True)
+    found = hit < size                                 # (T, 1)
     # probe distance of each empty slot from the home slot h (mod size);
     # the nearest one is where linear probing would land
     dist = (iota - h) & (size - 1)
     empty_dist = jnp.where(keys == -1, dist, size)
     first = jnp.min(empty_dist, axis=1, keepdims=True)  # (T, 1)
-    target = jnp.where(found,
-                       jnp.argmax(is_col, axis=1, keepdims=True
-                                  ).astype(jnp.int32),
-                       (h + first) & (size - 1))
+    target = jnp.where(found, hit, (h + first) & (size - 1))
     has_slot = found | (first < size)                  # (T, 1)
     write = (iota == target) & has_slot & use
     keys_ref[...] = jnp.where(write, col, keys)
@@ -94,10 +98,9 @@ def _probe_insert(keys_ref, vals_ref, col, v, use, size: int):
     return has_slot
 
 
-def _hash_kernel(a_rows_ref, a_vals_ref, a_starts_ref, a_lens_ref,
-                 b_cols_hbm, b_vals_hbm,
-                 keys_ref, vals_ref, skeys_ref, svals_ref, fail_ref,
-                 bcol_scratch, bval_scratch, sem_c, sem_v,
+def _hash_kernel(a_vals_ref, a_starts_ref, a_lens_ref, b_cols_hbm,
+                 b_vals_hbm, keys_ref, vals_ref, skeys_ref, svals_ref,
+                 fail_ref, bcol_buf, bval_buf, sem,
                  *, table: int, spill: int, f_chunk: int, tile: int):
     keys_ref[...] = jnp.full_like(keys_ref, -1)
     vals_ref[...] = jnp.zeros_like(vals_ref)
@@ -105,50 +108,68 @@ def _hash_kernel(a_rows_ref, a_vals_ref, a_starts_ref, a_lens_ref,
     svals_ref[...] = jnp.zeros_like(svals_ref)
     fail_ref[...] = jnp.zeros_like(fail_ref)
 
-    e_total = a_rows_ref.shape[1]
-    nnz_pad = b_cols_hbm.shape[0]
+    e_total = a_starts_ref.shape[1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile, F_CHUNK), 1)
+
+    def lanes(ref, e):
+        """(T, 1) vector of the tile rows' SMEM scalars at ELL slot e."""
+        out = jnp.zeros((tile, 1), ref.dtype)
+        for ti in range(tile):
+            out = jnp.where(row == ti, ref[ti, e], out)
+        return out
 
     def e_body(e, _):
-        # per-row lanes for A slot e: B-row id, A value, B-row start/len
-        ks = jax.lax.dynamic_slice(a_rows_ref[...], (0, e), (tile, 1))
-        avs = jax.lax.dynamic_slice(a_vals_ref[...], (0, e), (tile, 1))
-        starts = jax.lax.dynamic_slice(a_starts_ref[...], (0, e), (tile, 1))
-        lens = jax.lax.dynamic_slice(a_lens_ref[...], (0, e), (tile, 1))
-        lengths = jnp.where(ks >= 0, lens, 0)          # (T, 1)
+        # per-row lanes for A slot e: A value, B-row start/len
+        avs = lanes(a_vals_ref, e)
+        starts = lanes(a_starts_ref, e)
+        lens = lanes(a_lens_ref, e)
         # rows stream their B rows in lockstep; rows whose B row ran out
-        # are masked by in_row below, so the shared chunk count is the
+        # are masked by `use` below, so the shared chunk count is the
         # tile's max — per-row insert order is untouched by the batching
-        n_chunks = pl.cdiv(jnp.max(lengths), f_chunk)
+        max_len = a_lens_ref[0, e]
+        for ti in range(1, tile):
+            max_len = jnp.maximum(max_len, a_lens_ref[ti, e])
 
         def c_body(c, _):
-            src = jnp.clip(starts + c * f_chunk, 0, nnz_pad - f_chunk)
-            # one DMA per tile row (starts differ per row); all T copies
+            # each row's chunk [start + c*f_chunk, +f_chunk) lies in the
+            # two B blocks from its first element's block; all 4T copies
             # are in flight together before the first wait
             copies = []
             for ti in range(tile):
-                cp_c = pltpu.make_async_copy(
-                    b_cols_hbm.at[pl.ds(src[ti, 0], f_chunk)],
-                    bcol_scratch.at[ti], sem_c.at[ti])
-                cp_v = pltpu.make_async_copy(
-                    b_vals_hbm.at[pl.ds(src[ti, 0], f_chunk)],
-                    bval_scratch.at[ti], sem_v.at[ti])
-                cp_c.start()
-                cp_v.start()
-                copies.append((cp_c, cp_v))
-            for cp_c, cp_v in copies:
-                cp_c.wait()
-                cp_v.wait()
-            # chunk may start below `start` after the clip; recompute offsets
-            pos = jax.lax.broadcasted_iota(jnp.int32, (tile, f_chunk), 1) + src
-            in_row = (pos >= starts) & (pos < starts + lengths)
-            cols = bcol_scratch[...]                   # (T, f_chunk)
-            bvals = bval_scratch[...]
+                blk = (a_starts_ref[ti, e] + c * f_chunk) // F_CHUNK
+                for half in range(2):
+                    src = pl.ds(blk + half, 1)
+                    dst = (half, pl.ds(ti, 1), slice(None))
+                    copies.append(pltpu.make_async_copy(
+                        b_cols_hbm.at[src, :], bcol_buf.at[dst], sem.at[0]))
+                    copies.append(pltpu.make_async_copy(
+                        b_vals_hbm.at[src, :], bval_buf.at[dst], sem.at[1]))
+            for cp in copies:
+                cp.start()
+            for cp in copies:
+                cp.wait()
+            first = starts + c * f_chunk                   # (T, 1)
+            pos0 = (first // F_CHUNK) * F_CHUNK + lane     # (T, F)
+            pos1 = pos0 + F_CHUNK
+            cols0, cols1 = bcol_buf[0], bcol_buf[1]
+            vals0, vals1 = bval_buf[0], bval_buf[1]
+            n_i = jnp.minimum(max_len - c * f_chunk, f_chunk)
 
             def i_body(i, _):
-                col = jax.lax.dynamic_slice(cols, (0, i), (tile, 1))
-                use = (jax.lax.dynamic_slice(in_row, (0, i), (tile, 1))
-                       & (col >= 0))
-                v = avs * jax.lax.dynamic_slice(bvals, (0, i), (tile, 1))
+                # element i of every row's chunk, picked out of its two
+                # blocks by position (one lane matches per row)
+                target = first + i
+                sel0 = pos0 == target
+                sel1 = pos1 == target
+                col = jnp.sum(jnp.where(sel0, cols0, 0)
+                              + jnp.where(sel1, cols1, 0),
+                              axis=1, keepdims=True)
+                bv = jnp.sum(jnp.where(sel0, vals0, 0)
+                             + jnp.where(sel1, vals1, 0),
+                             axis=1, keepdims=True)
+                use = (target < starts + lens) & (col >= 0)
+                v = avs * bv
                 ok_t = _probe_insert(keys_ref, vals_ref, col, v, use, table)
                 rem = use & ~ok_t
                 ok_s = _probe_insert(skeys_ref, svals_ref, col, v, rem,
@@ -156,10 +177,10 @@ def _hash_kernel(a_rows_ref, a_vals_ref, a_starts_ref, a_lens_ref,
                 fail_ref[...] += jnp.where(rem & ~ok_s, 1, 0)
                 return 0
 
-            jax.lax.fori_loop(0, f_chunk, i_body, 0)
+            jax.lax.fori_loop(0, n_i, i_body, 0)
             return 0
 
-        jax.lax.fori_loop(0, n_chunks, c_body, 0)
+        jax.lax.fori_loop(0, pl.cdiv(max_len, f_chunk), c_body, 0)
         return 0
 
     jax.lax.fori_loop(0, e_total, e_body, 0)
@@ -176,8 +197,7 @@ def spgemm_hash_bin(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
     a_vals:   (R, E) float — matching A values
     a_starts: (R, E) int32 — b_indptr[k] pregathered (pad = 0)
     a_lens:   (R, E) int32 — B-row lengths (pad = 0)
-    b_cols:   (nnzB_pad,) int32 — flat B column indices (HBM), padded by
-              >= f_chunk
+    b_cols:   (nnzB_pad,) int32 — flat B column indices (HBM)
     b_vals:   (nnzB_pad,) float
     table/spill: pow2 slot counts for the primary/spill tables.
     tile: rows probed per grid step (vectorized over the tile). R is
@@ -191,25 +211,24 @@ def spgemm_hash_bin(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
     r, e = a_rows.shape
     dtype = b_vals.dtype
     tile = max(int(tile), 1)
-    r_pad = ((r + tile - 1) // tile) * tile
-    if r_pad != r:
-        pad = ((0, r_pad - r), (0, 0))
-        a_rows = jnp.pad(a_rows, pad, constant_values=-1)
-        a_vals = jnp.pad(a_vals, pad)
-        a_starts = jnp.pad(a_starts, pad)
-        a_lens = jnp.pad(a_lens, pad)
+    if not 0 < f_chunk <= F_CHUNK:
+        raise ValueError(f"f_chunk must be in (0, {F_CHUNK}], got {f_chunk}")
+    r_pad = pl.cdiv(max(r, 1), tile) * tile
+    # inactive ELL slots (a_rows < 0) stream nothing; pad rows are inert
+    a_lens = pad_rows(jnp.where(a_rows >= 0, a_lens, 0), r_pad)
+    a_starts = pad_rows(a_starts, r_pad)
+    a_vals = pad_rows(a_vals, r_pad)
     kernel = functools.partial(_hash_kernel, table=table, spill=spill,
                                f_chunk=f_chunk, tile=tile)
+    smem_block = pl.BlockSpec((tile, e), lambda i: (i, 0),
+                              memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
         grid=(r_pad // tile,),
         in_specs=[
-            pl.BlockSpec((tile, e), lambda i: (i, 0)),
-            pl.BlockSpec((tile, e), lambda i: (i, 0)),
-            pl.BlockSpec((tile, e), lambda i: (i, 0)),
-            pl.BlockSpec((tile, e), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            smem_block, smem_block, smem_block,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((tile, table), lambda i: (i, 0)),
@@ -226,13 +245,10 @@ def spgemm_hash_bin(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
             jax.ShapeDtypeStruct((r_pad, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tile, f_chunk), jnp.int32),
-            pltpu.VMEM((tile, f_chunk), dtype),
-            pltpu.SemaphoreType.DMA((tile,)),
-            pltpu.SemaphoreType.DMA((tile,)),
+            pltpu.VMEM((2, tile, F_CHUNK), jnp.int32),
+            pltpu.VMEM((2, tile, F_CHUNK), dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals)
-    if r_pad != r:
-        out = [x[:r] for x in out]
-    return tuple(out)
+    )(a_vals, a_starts, a_lens, b_blocks(b_cols, -1), b_blocks(b_vals, 0))
+    return tuple(x[:r] for x in out)

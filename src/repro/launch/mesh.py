@@ -7,16 +7,12 @@ never touches jax device state — required because the dry-run forces a
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed in jax 0.5; older jax defaults every axis to Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover — depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_types(n: int) -> dict:
-    """make_mesh kwargs pinning explicit Auto axis types when available."""
-    return {} if AxisType is None else {"axis_types": (AxisType.Auto,) * n}
+    """make_mesh kwargs pinning explicit Auto axis types."""
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

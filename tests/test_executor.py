@@ -420,8 +420,7 @@ def test_esc_overflow_error_unified():
     p_cap = pow2_at_least(int(np.asarray(a.row_nnz()).sum()) ** 2 + 1,
                           floor=64)
     r = esc.esc_spgemm(a.indptr, a.indices, a.values, a.indptr, a.indices,
-                       a.values, p_cap=p_cap, out_cap=4, num_rows_a=a.m,
-                       n_cols_b=a.n)
+                       a.values, p_cap=p_cap, out_cap=4, num_rows_a=a.m)
     with pytest.raises(esc.EscOverflowError):
         esc.esc_to_csr(r, (a.m, a.n), 4)
     # executor slab path raises the same type

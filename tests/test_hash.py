@@ -142,7 +142,7 @@ def test_hash_kernel_matches_xla_bit_identical():
                                                     svals, fail))
     x_cols, x_vals, x_nnz = (np.asarray(x) for x in kops._hash_bin_xla(
         a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
-        table=table, spill=spill, n_cols=n_cols, p_cap=p_cap))
+        table=table, spill=spill, p_cap=p_cap))
     assert (k_nnz == x_nnz).all()
     for i in range(r):
         n = int(k_nnz[i])
@@ -197,7 +197,7 @@ def test_hash_kernel_overflow_flag_exact():
     p_cap = formats.pow2_at_least(sum(cases), floor=64)
     _, _, x_nnz = (np.asarray(x) for x in kops._hash_bin_xla(
         a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
-        table=table, spill=spill, n_cols=n_cols, p_cap=p_cap))
+        table=table, spill=spill, p_cap=p_cap))
     # non-overflow rows agree exactly; overflow rows cross the width
     # threshold on both backends (counts there are diagnostic only — the
     # merge discards the slab row and reroutes to the exact ESC fallback)
@@ -246,7 +246,7 @@ def test_hash_kernel_tile_boundaries(r):
     a_lens = work[3]
     p_cap = formats.pow2_at_least(int(a_lens.sum()), floor=64)
     x_out = tuple(np.asarray(x) for x in kops._hash_bin_xla(
-        *work, table=table, spill=spill, n_cols=512, p_cap=p_cap))
+        *work, table=table, spill=spill, p_cap=p_cap))
     nnz = outs[1][2]
     assert (nnz == x_out[2]).all()
     for i in range(r):
@@ -262,7 +262,7 @@ def test_hash_bin_op_tile_invariant_through_backend():
     table, spill = 64, binning.hash_spill_of(64)
     p_cap = formats.pow2_at_least(int(work[3].sum()), floor=64)
     outs = [tuple(np.asarray(x) for x in kops.hash_bin_op(
-        *work, table=table, spill=spill, n_cols=512, p_cap=p_cap,
+        *work, table=table, spill=spill, p_cap=p_cap,
         tile=tile)) for tile in (1, 8)]
     for x, y in zip(*outs):
         np.testing.assert_array_equal(x, y)
@@ -412,18 +412,21 @@ def test_tuning_cache_measures_once_and_lru():
 
 
 def test_tuning_failure_falls_back_to_default(monkeypatch):
+    """A failing measurement no longer falls back to DEFAULT_TUNING: it
+    raises, caches nothing, and the next plan measures again."""
     cache = tuning.TuningCache()
+    calls = []
 
     def boom(rung):
+        calls.append(rung)
         raise RuntimeError("no backend")
 
     monkeypatch.setattr(tuning, "_measure", boom)
-    t = tuning.hash_tuning_for(512, cache=cache)
-    assert t == tuning.DEFAULT_TUNING
-    assert t.tile_rows == 8 and t.f_chunk == 128
-    # the failure is cached: probed once, not per plan
-    assert tuning.hash_tuning_for(512, cache=cache) == tuning.DEFAULT_TUNING
-    assert cache.stats()["hits"] == 1
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="no backend"):
+            tuning.hash_tuning_for(512, cache=cache)
+    assert calls == [512, 512]
+    assert len(cache) == 0 and cache.stats()["hits"] == 0
 
 
 def test_tuning_measures_through_real_backend_path(monkeypatch):

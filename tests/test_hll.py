@@ -156,8 +156,10 @@ def test_small_range_gate_boundary_lockstep(v):
     assert est == pytest.approx(want, rel=1e-4), (v, est, want)
     # Pallas merge kernel finalizes through the identical gate (lockstep)
     sk = np.stack([regs, np.zeros(m, np.int32)]).astype(np.int32)
-    a_ell = np.array([[0, 1]], np.int32)  # row 1 = all-zero sentinel
-    merged, est_k = khll.hll_merge(jnp.asarray(a_ell), jnp.asarray(sk),
+    # one A row over B rows 0 and 1 (row 1 = all-zero sentinel)
+    merged, est_k = khll.hll_merge(jnp.asarray([0, 2], jnp.int32),
+                                   jnp.asarray([0, 1], jnp.int32),
+                                   jnp.asarray(sk),
                                    interpret=kops.use_interpret())
     np.testing.assert_array_equal(np.asarray(merged)[0], regs)
     assert float(np.asarray(est_k)[0]) == pytest.approx(want, rel=1e-4)

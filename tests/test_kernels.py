@@ -31,8 +31,10 @@ def test_hll_merge_kernel_sweep(m_regs, ra, k, nb):
     a_ell = rng.integers(0, nb, (ra, k)).astype(np.int32)
     for i in range(ra):
         a_ell[i, rng.integers(1, k + 1):] = nb  # sentinel padding
-    merged, est = khll.hll_merge(jnp.asarray(a_ell), jnp.asarray(sk),
-                                 interpret=True)
+    indptr = np.arange(ra + 1, dtype=np.int32) * k
+    merged, est = khll.hll_merge(jnp.asarray(indptr),
+                                 jnp.asarray(a_ell.reshape(-1)),
+                                 jnp.asarray(sk), interpret=True)
     mref, eref = kref.hll_merge_ref(jnp.asarray(a_ell), jnp.asarray(sk))
     np.testing.assert_array_equal(np.asarray(merged), np.asarray(mref))
     np.testing.assert_allclose(np.asarray(est), np.asarray(eref), rtol=1e-5)

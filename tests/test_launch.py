@@ -62,11 +62,8 @@ def test_dryrun_smoke_small_mesh():
         from repro.optim import AdamWConfig, adamw_init
         from repro.optim.adamw import AdamWState
 
-        try:  # AxisType landed in jax 0.5; older jax defaults to Auto anyway
-            from jax.sharding import AxisType
-            mesh_kw = dict(axis_types=(AxisType.Auto,) * 2)
-        except ImportError:
-            mesh_kw = {}
+        from jax.sharding import AxisType
+        mesh_kw = dict(axis_types=(AxisType.Auto,) * 2)
         cfg = configs.get_config("qwen3-1.7b", smoke=True)
         mesh = jax.make_mesh((2, 4), ("data", "model"), **mesh_kw)
         pol = ShardingPolicy(mesh, "fsdp")
@@ -96,11 +93,8 @@ def test_dryrun_multipod_mesh_small():
         from repro.launch.sharding import ShardingPolicy
         from repro.models import lm
 
-        try:  # AxisType landed in jax 0.5; older jax defaults to Auto anyway
-            from jax.sharding import AxisType
-            mesh_kw = dict(axis_types=(AxisType.Auto,) * 3)
-        except ImportError:
-            mesh_kw = {}
+        from jax.sharding import AxisType
+        mesh_kw = dict(axis_types=(AxisType.Auto,) * 3)
         cfg = configs.get_config("qwen3-1.7b", smoke=True)
         mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **mesh_kw)
         pol = ShardingPolicy(mesh, "tp")

@@ -182,7 +182,7 @@ def test_symbolic_exact_host_matches_jit_path():
         dev = esc.symbolic_exact(
             jnp.asarray(a.indptr), jnp.asarray(a.indices),
             jnp.asarray(b.indptr), jnp.asarray(b.indices),
-            num_rows_a=a.m, n_cols_b=b.n, p_cap=p_cap)
+            num_rows_a=a.m, p_cap=p_cap)
         np.testing.assert_array_equal(host, np.asarray(dev))
         assert host.dtype == np.int32
 

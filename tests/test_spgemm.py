@@ -127,6 +127,48 @@ def test_longrow_path_exercised():
     assert_csr_equal(c2, ref)
 
 
+@pytest.mark.parametrize("force", [None, "symbolic"])
+def test_wide_output_keys_do_not_wrap(force):
+    """(rows + 1) * n_cols >= 2**31 with x64 disabled: a packed int
+    (row, col) key would wrap. The ESC bins (upper-bound workflow), the
+    hash twin (symbolic workflow) and the oracle sort on (row, col)
+    instead, so all of them agree with scipy."""
+    m, k, n = 2500, 5000, 1_000_000
+    assert (m + 1) * n >= 2**31
+    a = formats.random_uniform_csr(3, m, k, 4.0)
+    b = formats.random_uniform_csr(4, k, n, 3.0)
+    c, rep = workflow.ocean_spgemm(a, b, force_workflow=force, cache=False)
+    assert rep.workflow == (force or "upper_bound")
+    busy = {key for key, rows in rep.bins.items() if rows}
+    assert ("esc" in busy) if force is None else any(
+        key.startswith("hash_t") for key in busy), rep.bins
+    assert workflow.scipy_mismatch(c, a, b) is None
+    assert workflow.scipy_mismatch(workflow.spgemm_reference(a, b),
+                                   a, b) is None
+
+
+def test_longrow_past_tile_cap_routes_to_esc():
+    """Long rows narrower than LONGROW_MAX_TILES column tiles keep the
+    column-tiled kernel; wider ones take the exact ESC bin instead."""
+    from repro.core import binning
+    m = 4
+    products = np.full(m, 5000)
+    pred = products.astype(float)
+    a_nnz = np.full(m, 50)
+    lo = np.zeros(m, np.int64)
+    for tiles, to_esc in ((binning.LONGROW_MAX_TILES, False),
+                          (binning.LONGROW_MAX_TILES + 1, True)):
+        n = tiles * binning.LONGROW_TILE
+        bp = binning.plan_bins(pred, products, lo, lo + n - 1, a_nnz, n,
+                               expansion=1.0, workflow="symbolic",
+                               hash_enabled=False)
+        long_bins = [bn for bn in bp.dense_bins if bn.is_longrow]
+        assert len(bp.esc_rows) == (m if to_esc else 0)
+        assert bool(long_bins) != to_esc
+        if long_bins:
+            assert long_bins[0].col_tiles == tiles
+
+
 def test_analysis_table1_selection():
     cfg = OceanConfig()
     # hypersparse -> upper_bound (avg products < 64)
