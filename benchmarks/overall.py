@@ -103,14 +103,18 @@ def run(rows: list, scale: int = 1):
                 f"overflow_causes={causes}"))
 
         # per-rung hash-kernel timing: the multi-row tiled kernel (the
-        # bin's autotuned tile) against its tile=1 row-sequential
-        # degeneracy, both through the real dispatching backend path
-        # (kops.hash_bin_op — Pallas on TPU / forced-interpret, XLA twin
-        # otherwise, where tile is a no-op and the two times tie)
+        # bin's autotuned tile) against a base tile, both through the real
+        # dispatching backend path (kops.hash_bin_op — compiled Pallas on
+        # TPU, interpreted Pallas under REPRO_CPU_NUMERIC=pallas, XLA twin
+        # otherwise, where tile is a no-op and the two times tie). The base
+        # is the tile=1 row-sequential degeneracy where the kernel is
+        # interpreted and the smallest compiled tile (8 rows) on TPU.
         plan_obj = planner.build_plan(a, a)
         if plan_obj.hash:
             b_cols_pad, b_vals_pad = kops.pad_b_flat(a)
             a_vals_np = np.asarray(a.values)
+            base = (1 if kops.use_interpret()
+                    else min(tuning.TILE_CANDIDATES_PALLAS))
             for hb in plan_obj.hash:
                 a_vals = kops.gather_bin_values(a_vals_np, hb.pos, hb.valid)
 
@@ -122,15 +126,15 @@ def run(rows: list, scale: int = 1):
                         f_chunk=hb.f_chunk, tile=tile))
 
                 rung_call(hb.tile)  # compile outside the timed region
-                rung_call(1)        # (timeit skips warmup under --smoke)
+                rung_call(base)     # (timeit skips warmup under --smoke)
                 t_tiled = timeit(lambda: rung_call(hb.tile))
-                t_seq = timeit(lambda: rung_call(1))
+                t_base = timeit(lambda: rung_call(base))
                 rows.append((
                     f"overall/{name}/kernel_rung/hash_t{hb.table}",
                     t_tiled * 1e6,
                     f"tile={hb.tile} rows={hb.n_valid} "
-                    f"tile1_us={t_seq * 1e6:.1f} "
-                    f"tile_speedup=x{t_seq / max(t_tiled, 1e-12):.2f}"))
+                    f"base_tile={base} base_us={t_base * 1e6:.1f} "
+                    f"tile_speedup=x{t_base / max(t_tiled, 1e-12):.2f}"))
 
         # threaded-executor overlap: merge work the worker thread ran
         # while the collect loop was still pulling slabs (feeds the CI

@@ -234,9 +234,9 @@ def main() -> None:
                                                if threaded_fracs else None),
                "threaded_merge_overlap_frac_by_row": threaded_fracs,
                # per-rung hash-kernel timing: the multi-row tiled kernel
-               # vs its tile=1 row-sequential degeneracy, through the real
-               # dispatching backend path (the two tie on the XLA twin,
-               # where the tile knob is a no-op)
+               # vs a base tile (tile=1 interpreted, 8 rows compiled),
+               # through the real dispatching backend path (the two tie on
+               # the XLA twin, where the tile knob is a no-op)
                "kernel_us_by_rung": kernel_us_by_rung,
                "kernel_tile_speedup_by_rung": kernel_tile_speedup,
                # binning prework overlapped behind analysis wave 2 at

@@ -8,7 +8,10 @@ with 8 -- through ``workflow.ocean_spgemm``, twice each (cold: fresh plan
 and compiles; warm: plan-cache hit). The uniform multiply takes the
 workflow the selector picks; the power-law one is forced to ``estimation``,
 so the HLL merge kernel and the dense and hash rungs all receive rows.
-Each C is checked against scipy (``workflow.scipy_mismatch``).
+Each C is checked against scipy (``workflow.scipy_mismatch``). The
+estimation path is checked too: the merge kernel's registers and
+estimates for the power-law A against the XLA merge, and the plan's
+estimation error (``measure_accuracy``) is printed.
 
 ``--chips 4`` runs only the sharded path: the uniform multiply with
 ``devices=4, analysis_devices=4`` against the same multiply on one device.
@@ -90,6 +93,34 @@ def _check(name, c, a) -> str | None:
     return None if bad is None else f"{name}: {bad}"
 
 
+def _check_merge(a, er) -> str | None:
+    """The HLL merge kernel (compiled Pallas) against the XLA merge and
+    estimate, on the B-row sketches the estimation workflow builds."""
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core import hll as chll
+    from repro.core.analysis import OceanConfig, sketches_for
+    from repro.kernels import ops as kops
+    cfg = OceanConfig()
+    sk = sketches_for(a, cfg.m_regs(er), cfg.seed)
+    sentinel = jnp.zeros((1, sk.shape[1]), jnp.int32)
+    merged, est = kops.merge_estimate_op(a, jnp.concatenate([sk, sentinel]))
+    merged_x = chll.merge_sketches(a.indptr, a.indices, sk, num_rows_a=a.m)
+    est_x = np.asarray(chll.estimate_cardinality(merged_x))
+    same = np.array_equal(np.asarray(merged), np.asarray(merged_x))
+    rel = float(np.max(np.abs(np.asarray(est) - est_x)
+                       / np.maximum(est_x, 1.0)))
+    print(f"powerlaw hll_merge vs XLA: m_regs={sk.shape[1]} "
+          f"registers_identical={same} est_max_rel_diff={rel:.3g}",
+          flush=True)
+    if not same:
+        return "HLL merge kernel registers differ from the XLA merge"
+    # the two estimates sum the same exp2 terms in different orders
+    if rel > 1e-4:
+        return f"HLL merge kernel estimates differ from XLA by {rel:.3g}"
+    return None
+
+
 def one_chip(seed: int) -> int:
     from repro.core import formats
     t0 = time.perf_counter()
@@ -108,6 +139,12 @@ def one_chip(seed: int) -> int:
         bad = _check(name, c, mats[name])
         if bad:
             return _fail(bad)
+        if rep.workflow == "estimation":
+            print(f"{name} estimation accuracy: "
+                  f"{rep.estimation_accuracy.summary()}", flush=True)
+            bad = _check_merge(mats[name], rep.decision["er"])
+            if bad:
+                return _fail(bad)
     if "estimation" not in workflows:
         return _fail(f"no multiply took the estimation workflow: {workflows}")
     for rung in ("dense", "hash"):

@@ -33,9 +33,12 @@ CAP_LADDER = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 # Column tile for the long-row kernel.
 LONGROW_TILE = 2048
 # Most column tiles a long row may take. The column-tiled kernel re-streams
-# the row's B rows once per tile and materializes a dense slab as wide as C,
-# so its work grows as products x tiles; past this many tiles the exact ESC
-# sort (work O(products), capacity = the product upper bound) takes the row.
+# the row's B rows once per tile and materializes a dense slab as wide as C
+# (8 bytes per column per row), which its compaction then sorts. Over a
+# 2**18-wide C, 4,158 long rows need 20.3 GiB of HBM to compact, past a
+# v5e's 15.75 GiB (the TPU compiler refuses it); at 8 tiles the same rows
+# need under 1 GiB. Past this many tiles the exact ESC sort (work
+# O(products), capacity = the product upper bound) takes the row.
 LONGROW_MAX_TILES = 8
 # Paper: smallest block size / ESC threshold.
 ESC_THRESHOLD = 64
@@ -191,7 +194,8 @@ def plan_bins(pred_nnz: np.ndarray, products: np.ndarray,
     empty = products == 0
     esc_mask = np.zeros(m, bool)
     if esc_enabled and workflow == "upper_bound":
-        # Paper §3.3: ESC only in the upper-bound workflow, for short rows.
+        # Paper §3.3: ESC only in the upper-bound workflow, for short rows
+        # (long rows past LONGROW_MAX_TILES below take it in any workflow).
         esc_mask = (~empty) & (products < ESC_THRESHOLD)
 
     dense_mask = (~empty) & (~esc_mask)

@@ -42,7 +42,9 @@ def hll_estimate_from_regs(regs: jax.Array, clip_max: float | None = None):
     e_raw = _alpha(m) * m * m / inv_sum
     v = jnp.sum(regs == 0, axis=-1).astype(jnp.float32)
     e_small = m * jnp.log(jnp.where(v > 0, m / jnp.maximum(v, 1e-9), 1.0))
-    e = jnp.where((e_raw <= 2.5 * m) & (v > 0), e_small, e_raw)
+    # small-range gate on the linear-counting estimate, as in
+    # core.hll.estimate_cardinality and the merge kernel
+    e = jnp.where((e_small <= 2.5 * m) & (v > 0), e_small, e_raw)
     if clip_max is not None:
         e = jnp.clip(e, 0.0, clip_max)
     return e

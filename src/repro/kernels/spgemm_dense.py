@@ -18,7 +18,7 @@ TPU adaptation of the paper's accumulation kernels (§3.3):
   accumulator window and the B-row chunk live in VMEM; the B nonzero stream
   and the output slab stay in HBM and are moved by explicit async DMA.
   A-side scalars (B-row starts, lengths, A values) sit in SMEM, where the
-  scalar core reads them at dynamic indices.
+  scalar core reads them at dynamic indices, in ``ELL_TILE``-slot blocks.
 
 * B's flat nonzero arrays are streamed as lane-dense ``(N, 128)`` blocks
   (:func:`b_blocks`): a DMA moves whole 128-lane block rows, and a chunk
@@ -30,11 +30,13 @@ TPU adaptation of the paper's accumulation kernels (§3.3):
   trade the paper's global-memory fallback makes (its §5.4 ``torso1``
   pathology corresponds exactly to a high re-stream factor here).
 
-Grid: ``(rows / ROW_TILE, col_tiles)``; col_tiles == 1 for windowed
-(binned) rows. Each program owns one ``(ROW_TILE, W)`` output block and
-accumulates its rows one after another: no cross-program races, which is
-what the per-row binning guarantees on GPU too. Rows are padded to a
-``ROW_TILE`` multiple with inert rows inside the wrappers.
+Grid: ``(rows / ROW_TILE, col_tiles, ell_width / ELL_TILE)``; col_tiles
+== 1 for windowed (binned) rows. Each ``(ROW_TILE, W)`` output block stays
+resident across the innermost ELL axis and accumulates its rows one after
+another: no cross-program races, which is what the per-row binning
+guarantees on GPU too, and each row still takes its A slots in ascending
+order. Rows and ELL slots are padded to tile multiples with inert entries
+inside the wrappers.
 """
 from __future__ import annotations
 
@@ -51,6 +53,11 @@ F_CHUNK = 128
 # Output rows per grid step: the f32 sublane tile, the smallest row block
 # the TPU lowering accepts over an R-row array.
 ROW_TILE = 8
+# A-side ELL slots per grid step. A bin's ELL width follows its longest A
+# row, which nothing bounds; tiling it keeps each (rows, ELL_TILE) SMEM
+# block of A scalars small (three arrays, double-buffered, at 16 rows:
+# 192 KiB of the v5e's 1 MiB SMEM).
+ELL_TILE = 512
 
 
 def b_blocks(flat: jax.Array, fill) -> jax.Array:
@@ -62,11 +69,11 @@ def b_blocks(flat: jax.Array, fill) -> jax.Array:
     return flat.reshape(n_blk, F_CHUNK)
 
 
-def pad_rows(x: jax.Array, rows: int, fill=0) -> jax.Array:
-    """Pad the leading (row) axis of ``x`` to ``rows`` with ``fill``."""
-    if x.shape[0] == rows:
+def pad_to(x: jax.Array, shape, fill=0) -> jax.Array:
+    """Pad each axis of ``x`` at its end up to ``shape`` with ``fill``."""
+    if x.shape == tuple(shape):
         return x
-    pad = [(0, rows - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    pad = [(0, s - d) for s, d in zip(shape, x.shape)]
     return jnp.pad(x, pad, constant_values=fill)
 
 
@@ -78,11 +85,16 @@ def _accumulate_kernel(*refs, window: int, with_values: bool):
     if with_values:
         (a_vals_ref, a_starts_ref, a_lens_ref, row_lo_ref, b_cols_hbm,
          b_vals_hbm, acc_ref, cnt_ref, bcol_buf, bval_buf, sem) = refs
-        acc_ref[...] = jnp.zeros_like(acc_ref)
     else:
         (a_starts_ref, a_lens_ref, row_lo_ref, b_cols_hbm, cnt_ref,
          bcol_buf, sem) = refs
-    cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        if with_values:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
     t = pl.program_id(1)
     e_total = a_starts_ref.shape[1]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, F_CHUNK), 1)
@@ -143,27 +155,29 @@ def _bin_call(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols, b_vals, *,
     ``b_vals`` None selects the count-only variant)."""
     r, e = a_rows.shape
     r_pad = pl.cdiv(max(r, 1), ROW_TILE) * ROW_TILE
+    et = min(e, ELL_TILE)
+    e_pad = pl.cdiv(e, et) * et
     out_w = col_tiles * window
     with_values = a_vals is not None
-    # inactive ELL slots (a_rows < 0) stream nothing
-    a_lens = pad_rows(jnp.where(a_rows >= 0, a_lens, 0), r_pad)
-    a_starts = pad_rows(a_starts, r_pad)
-    row_lo = pad_rows(row_lo, r_pad)
-    smem_block = pl.BlockSpec((ROW_TILE, e), lambda i, t: (i, 0),
+    # inactive ELL slots (a_rows < 0) and pad slots stream nothing
+    a_lens = pad_to(jnp.where(a_rows >= 0, a_lens, 0), (r_pad, e_pad))
+    a_starts = pad_to(a_starts, (r_pad, e_pad))
+    row_lo = pad_to(row_lo, (r_pad, 1))
+    smem_block = pl.BlockSpec((ROW_TILE, et), lambda i, t, k: (i, k),
                               memory_space=pltpu.SMEM)
     in_specs = [smem_block, smem_block,
-                pl.BlockSpec((ROW_TILE, 1), lambda i, t: (i, 0),
+                pl.BlockSpec((ROW_TILE, 1), lambda i, t, k: (i, 0),
                              memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pl.ANY)]
     args = [a_starts, a_lens, row_lo, b_blocks(b_cols, -1)]
-    out_block = pl.BlockSpec((ROW_TILE, window), lambda i, t: (i, t))
+    out_block = pl.BlockSpec((ROW_TILE, window), lambda i, t, k: (i, t))
     out_specs = [out_block]
     out_shape = [jax.ShapeDtypeStruct((r_pad, out_w), jnp.float32)]
     scratch = [pltpu.VMEM((1, F_CHUNK), jnp.int32)]
     if with_values:
         in_specs = [smem_block] + in_specs + [
             pl.BlockSpec(memory_space=pl.ANY)]
-        args = ([pad_rows(a_vals, r_pad)] + args
+        args = ([pad_to(a_vals, (r_pad, e_pad))] + args
                 + [b_blocks(b_vals, 0)])
         out_specs = [out_block, out_block]
         out_shape = [jax.ShapeDtypeStruct((r_pad, out_w), b_vals.dtype)
@@ -172,7 +186,7 @@ def _bin_call(a_rows, a_vals, a_starts, a_lens, row_lo, b_cols, b_vals, *,
     out = pl.pallas_call(
         functools.partial(_accumulate_kernel, window=window,
                           with_values=with_values),
-        grid=(r_pad // ROW_TILE, col_tiles),
+        grid=(r_pad // ROW_TILE, col_tiles, e_pad // et),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,

@@ -41,10 +41,12 @@ rows (no A entries) inside :func:`spgemm_hash_bin`, so callers never see
 the tiling. Compiled for a TPU, the tile must be a multiple of 8 (the
 sublane tile); other tiles run only in interpret mode.
 
-A-side scalars (A values, B-row starts and lengths) sit in SMEM; each
-step assembles the tile's (T, 1) lanes from them. B streams as
-lane-dense ``(N, 128)`` blocks: a row's chunk lies in two consecutive
-blocks, and each step picks its element out of them by position.
+A-side scalars (A values, B-row starts and lengths) sit in SMEM, in
+``ELL_TILE``-slot blocks along a second, innermost grid axis over which
+the tile's output blocks stay resident; each step assembles the tile's
+(T, 1) lanes from them. B streams as lane-dense ``(N, 128)`` blocks: a
+row's chunk lies in two consecutive blocks, and each step picks its
+element out of them by position.
 """
 from __future__ import annotations
 
@@ -55,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .spgemm_dense import F_CHUNK, b_blocks, pad_rows
+from .spgemm_dense import ELL_TILE, F_CHUNK, b_blocks, pad_to
 
 # Knuth's multiplicative (Fibonacci) hash constant: 2**32 / phi.
 _FIB_MULT = 2654435769
@@ -102,13 +104,17 @@ def _hash_kernel(a_vals_ref, a_starts_ref, a_lens_ref, b_cols_hbm,
                  b_vals_hbm, keys_ref, vals_ref, skeys_ref, svals_ref,
                  fail_ref, bcol_buf, bval_buf, sem,
                  *, table: int, spill: int, f_chunk: int, tile: int):
-    keys_ref[...] = jnp.full_like(keys_ref, -1)
-    vals_ref[...] = jnp.zeros_like(vals_ref)
-    skeys_ref[...] = jnp.full_like(skeys_ref, -1)
-    svals_ref[...] = jnp.zeros_like(svals_ref)
-    fail_ref[...] = jnp.zeros_like(fail_ref)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        keys_ref[...] = jnp.full_like(keys_ref, -1)
+        vals_ref[...] = jnp.zeros_like(vals_ref)
+        skeys_ref[...] = jnp.full_like(skeys_ref, -1)
+        svals_ref[...] = jnp.zeros_like(svals_ref)
+        fail_ref[...] = jnp.zeros_like(fail_ref)
 
     e_total = a_starts_ref.shape[1]
+    # last first block of a two-block window inside the padded B arrays
+    last_blk = b_cols_hbm.shape[0] - 2
     row = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (tile, F_CHUNK), 1)
 
@@ -134,10 +140,14 @@ def _hash_kernel(a_vals_ref, a_starts_ref, a_lens_ref, b_cols_hbm,
         def c_body(c, _):
             # each row's chunk [start + c*f_chunk, +f_chunk) lies in the
             # two B blocks from its first element's block; all 4T copies
-            # are in flight together before the first wait
+            # are in flight together before the first wait. A row whose own
+            # B row ended (c*f_chunk >= its length) may point past B's
+            # end: its window is clamped into the array, and `use` masks
+            # everything it reads
             copies = []
             for ti in range(tile):
-                blk = (a_starts_ref[ti, e] + c * f_chunk) // F_CHUNK
+                blk = jnp.minimum(
+                    (a_starts_ref[ti, e] + c * f_chunk) // F_CHUNK, last_blk)
                 for half in range(2):
                     src = pl.ds(blk + half, 1)
                     dst = (half, pl.ds(ti, 1), slice(None))
@@ -203,7 +213,9 @@ def spgemm_hash_bin(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
     tile: rows probed per grid step (vectorized over the tile). R is
           padded to a tile multiple with inert rows internally and the
           outputs sliced back, so per-row results are independent of
-          ``tile`` (``tile=1`` is the row-sequential degeneracy).
+          ``tile`` (``tile=1`` is the row-sequential degeneracy). A
+          compiled kernel takes multiples of 8 only; others raise
+          ValueError.
     Returns (keys (R, table) int32 with -1 empties, vals (R, table),
              skeys (R, spill), svals (R, spill), fail (R, 1) int32).
     ``fail > 0`` iff the row's distinct count exceeds table + spill.
@@ -213,29 +225,34 @@ def spgemm_hash_bin(a_rows, a_vals, a_starts, a_lens, b_cols, b_vals,
     tile = max(int(tile), 1)
     if not 0 < f_chunk <= F_CHUNK:
         raise ValueError(f"f_chunk must be in (0, {F_CHUNK}], got {f_chunk}")
+    if not interpret and tile % 8:
+        raise ValueError(f"a compiled tile must be a multiple of 8, got {tile}")
     r_pad = pl.cdiv(max(r, 1), tile) * tile
-    # inactive ELL slots (a_rows < 0) stream nothing; pad rows are inert
-    a_lens = pad_rows(jnp.where(a_rows >= 0, a_lens, 0), r_pad)
-    a_starts = pad_rows(a_starts, r_pad)
-    a_vals = pad_rows(a_vals, r_pad)
+    et = min(e, ELL_TILE)
+    e_pad = pl.cdiv(e, et) * et
+    # inactive ELL slots (a_rows < 0) stream nothing; pad rows and pad
+    # slots are inert
+    a_lens = pad_to(jnp.where(a_rows >= 0, a_lens, 0), (r_pad, e_pad))
+    a_starts = pad_to(a_starts, (r_pad, e_pad))
+    a_vals = pad_to(a_vals, (r_pad, e_pad))
     kernel = functools.partial(_hash_kernel, table=table, spill=spill,
                                f_chunk=f_chunk, tile=tile)
-    smem_block = pl.BlockSpec((tile, e), lambda i: (i, 0),
+    smem_block = pl.BlockSpec((tile, et), lambda i, k: (i, k),
                               memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
-        grid=(r_pad // tile,),
+        grid=(r_pad // tile, e_pad // et),
         in_specs=[
             smem_block, smem_block, smem_block,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((tile, table), lambda i: (i, 0)),
-            pl.BlockSpec((tile, table), lambda i: (i, 0)),
-            pl.BlockSpec((tile, spill), lambda i: (i, 0)),
-            pl.BlockSpec((tile, spill), lambda i: (i, 0)),
-            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((tile, table), lambda i, k: (i, 0)),
+            pl.BlockSpec((tile, table), lambda i, k: (i, 0)),
+            pl.BlockSpec((tile, spill), lambda i, k: (i, 0)),
+            pl.BlockSpec((tile, spill), lambda i, k: (i, 0)),
+            pl.BlockSpec((tile, 1), lambda i, k: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((r_pad, table), jnp.int32),

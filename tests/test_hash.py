@@ -255,6 +255,78 @@ def test_hash_kernel_tile_boundaries(r):
         np.testing.assert_array_equal(outs[1][1][i, :n], x_out[1][i, :n])
 
 
+@pytest.mark.parametrize("f_chunk", [128, 64])
+def test_hash_kernel_b_window_stays_in_bounds(f_chunk):
+    """One tile holds the last B row and a B row 300 entries longer: the
+    tile streams chunks for the long row while the short one has ended, so
+    the short row's window would start past B's end. Run in the TPU
+    interpreter, which raises on any out-of-bounds DMA read."""
+    from jax.experimental.pallas import tpu as pltpu
+    long_len, short_len, tile = 300, 10, 8
+    b_cols = (np.arange(long_len + short_len) % 97).astype(np.int32)
+    b_vals = np.arange(1, long_len + short_len + 1).astype(np.float32)
+    a_rows = np.full((tile, 1), -1, np.int32)
+    a_rows[:2, 0] = (0, 1)
+    a_starts = np.zeros((tile, 1), np.int32)
+    a_starts[1, 0] = long_len
+    a_lens = np.zeros((tile, 1), np.int32)
+    a_lens[:2, 0] = (long_len, short_len)
+    a_vals = np.ones((tile, 1), np.float32)
+    work = (a_rows, a_vals, a_starts, a_lens, b_cols, b_vals)
+    table, spill = 128, binning.hash_spill_of(128)
+    out = khash.spgemm_hash_bin(*work, table=table, spill=spill,
+                                f_chunk=f_chunk, tile=tile,
+                                interpret=pltpu.InterpretParams())
+    k_cols, k_vals, k_nnz = (np.asarray(x) for x in
+                             kops.extract_hash_rows(*out))
+    x_cols, x_vals, x_nnz = (np.asarray(x) for x in kops._hash_bin_xla(
+        *work, table=table, spill=spill, p_cap=512))
+    assert k_nnz.tolist() == x_nnz.tolist() == [97, 10] + [0] * 6
+    for i in range(2):
+        n = int(k_nnz[i])
+        np.testing.assert_array_equal(k_cols[i, :n], x_cols[i, :n])
+        np.testing.assert_array_equal(k_vals[i, :n], x_vals[i, :n])
+
+
+def test_hash_kernel_ell_tiled_bit_identical():
+    """A bin wider than ELL_TILE slots runs over several ELL grid steps;
+    each row still takes its slots in order, so the output matches the XLA
+    twin bit for bit."""
+    from repro.kernels.spgemm_dense import ELL_TILE
+    rng = np.random.default_rng(3)
+    r, e, nb, blen = 8, ELL_TILE + 88, 40, 2
+    b_cols = rng.integers(0, 80, nb * blen).astype(np.int32)
+    b_vals = rng.integers(1, 5, nb * blen).astype(np.float32)
+    a_rows = rng.integers(0, nb, (r, e)).astype(np.int32)
+    a_rows[3, 100:] = -1  # a short row among long ones
+    a_vals = rng.integers(1, 4, (r, e)).astype(np.float32)
+    a_starts = a_rows * blen
+    a_lens = np.where(a_rows >= 0, blen, 0).astype(np.int32)
+    work = (a_rows, a_vals, a_starts, a_lens, b_cols, b_vals)
+    table, spill = 64, binning.hash_spill_of(64)
+    out = khash.spgemm_hash_bin(*work, table=table, spill=spill,
+                                f_chunk=128, interpret=True)
+    k_out = tuple(np.asarray(x) for x in kops.extract_hash_rows(*out))
+    x_out = tuple(np.asarray(x) for x in kops._hash_bin_xla(
+        *work, table=table, spill=spill,
+        p_cap=formats.pow2_at_least(r * e * blen, floor=64)))
+    np.testing.assert_array_equal(k_out[2], x_out[2])
+    for i in range(r):
+        n = int(k_out[2][i])
+        assert 0 < n <= table + spill
+        np.testing.assert_array_equal(k_out[0][i, :n], x_out[0][i, :n])
+        np.testing.assert_array_equal(k_out[1][i, :n], x_out[1][i, :n])
+
+
+@pytest.mark.parametrize("tile", [1, 4, 12])
+def test_hash_kernel_compiled_tile_must_fill_sublanes(tile):
+    """A compiled kernel takes only multiples of the 8-row sublane tile;
+    other tiles fail up front instead of deep in the TPU lowering."""
+    work = _tile_workload(8)
+    with pytest.raises(ValueError, match="multiple of 8"):
+        khash.spgemm_hash_bin(*work, table=64, spill=32, tile=tile)
+
+
 def test_hash_bin_op_tile_invariant_through_backend():
     """kops.hash_bin_op output is invariant to the tile knob on whichever
     backend path is active (Pallas tiles the grid, XLA ignores it)."""

@@ -2,6 +2,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import formats
 from repro.kernels import hll as khll, ref as kref, spgemm_dense as kdense
@@ -40,6 +41,31 @@ def test_hll_merge_kernel_sweep(m_regs, ra, k, nb):
     np.testing.assert_allclose(np.asarray(est), np.asarray(eref), rtol=1e-5)
 
 
+@pytest.mark.parametrize("m_regs", [32, 64])
+@pytest.mark.parametrize("ra,lo,hi,nb", [(12, 129, 400, 300),
+                                         (1100, 0, 3, 50)])
+def test_hll_merge_kernel_ragged_rows(m_regs, ra, lo, hi, nb):
+    """Ragged CSR rows at unaligned starts: rows longer than one 128-entry
+    chunk, empty rows, sentinel entries and more rows than one merge
+    block. The TPU interpreter raises on any out-of-bounds DMA read."""
+    rng = np.random.default_rng(ra * hi + nb + m_regs)
+    bcols = rng.integers(0, 5000, (nb, 128)).astype(np.int32)
+    sk = np.asarray(kref.hll_sketch_ref(jnp.asarray(bcols), m_regs=m_regs))
+    sk = np.vstack([sk, np.zeros((1, m_regs), np.int32)])
+    lens = rng.integers(lo, hi + 1, ra)
+    indptr = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+    idx = rng.integers(0, nb + 1, int(indptr[-1])).astype(np.int32)
+    a_ell = np.full((ra, hi), nb, np.int32)  # sentinel padding
+    for i in range(ra):
+        a_ell[i, :lens[i]] = idx[indptr[i]:indptr[i + 1]]
+    merged, est = khll.hll_merge(jnp.asarray(indptr), jnp.asarray(idx),
+                                 jnp.asarray(sk),
+                                 interpret=pltpu.InterpretParams())
+    mref, eref = kref.hll_merge_ref(jnp.asarray(a_ell), jnp.asarray(sk))
+    np.testing.assert_array_equal(np.asarray(merged), np.asarray(mref))
+    np.testing.assert_allclose(np.asarray(est), np.asarray(eref), rtol=1e-5)
+
+
 def _random_bin(seed, nB, n, R, E, dtype):
     rng = np.random.default_rng(seed)
     b = formats.random_uniform_csr(seed, nB, n, 10.0, dtype=dtype)
@@ -58,7 +84,8 @@ def _random_bin(seed, nB, n, R, E, dtype):
 
 
 @pytest.mark.parametrize("dtype", [np.float32])
-@pytest.mark.parametrize("R,E,W", [(4, 8, 256), (8, 16, 512), (16, 4, 1024)])
+@pytest.mark.parametrize("R,E,W", [(4, 8, 256), (8, 16, 512), (16, 4, 1024),
+                                   (8, kdense.ELL_TILE + 88, 256)])
 def test_dense_kernel_sweep(dtype, R, E, W):
     nB, n = 48, W - 16
     (b, a_rows, a_vals, a_starts, a_lens,
@@ -75,6 +102,11 @@ def test_dense_kernel_sweep(dtype, R, E, W):
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_array_equal(
         np.asarray(cnt).astype(np.int32), np.asarray(rcnt))
+    # the count-only variant tiles the ELL axis the same way
+    cnt_only = kdense.spgemm_count_bin(
+        jnp.asarray(a_rows), jnp.asarray(a_starts), jnp.asarray(a_lens),
+        jnp.asarray(row_lo), b_cols_p, window=W, interpret=True)
+    np.testing.assert_array_equal(np.asarray(cnt_only), np.asarray(cnt))
 
 
 def test_dense_kernel_windowed_offset():

@@ -63,8 +63,10 @@ def _bin_shapes(rows, ell, with_values=True):
 
 
 # (rows, ELL width, window, column tiles): the power-law smoke's narrow
-# dense bin, the widest window, and a column-tiled long-row bin
-DENSE_BINS = [(224270, 8, 256, 1), (64, 2048, 4096, 1), (2112, 2048, 2048, 8)]
+# dense bin, the widest window, a column-tiled long-row bin, and hub rows
+# far wider than SMEM could hold as one block of A scalars
+DENSE_BINS = [(224270, 8, 256, 1), (64, 2048, 4096, 1), (2112, 2048, 2048, 8),
+              (16, 65536, 4096, 1)]
 
 
 @pytest.mark.parametrize("rows,ell,window,tiles", DENSE_BINS)
@@ -84,10 +86,10 @@ def test_spgemm_count_bin_compiles(one_chip, rows, ell, window, tiles):
 
 
 # (rows, ELL width, table, tile, f_chunk): the uniform smoke's largest bin,
-# the largest table (2048 + 1024 spill), and the smallest table at the
-# autotuner's other tile and chunk candidates
+# the largest table (2048 + 1024 spill), the smallest table at the
+# autotuner's other tile and chunk candidates, and hub rows
 HASH_BINS = [(209159, 32, 512, 8, 128), (1905, 1024, 2048, 16, 64),
-             (25246, 32, 32, 16, 128)]
+             (25246, 32, 32, 16, 128), (32, 65536, 2048, 16, 128)]
 
 
 @pytest.mark.parametrize("rows,ell,table,tile,f_chunk", HASH_BINS)
