@@ -32,8 +32,9 @@ import numpy as np
 from repro.obs import trace
 from . import hll
 from .dispatch import (DeviceSpec, Launch, collect_in_completion_order,
-                       device_context, overlap_host_work, resolve_devices,
-                       start_async_host_copies)
+                       device_context, new_copy_bytes, overlap_host_work,
+                       resolve_devices, start_async_host_copies, to_device,
+                       to_host)
 from .formats import CSR, flat_gather_index, pow2_at_least
 from .hll import row_ids_from_indptr
 
@@ -215,6 +216,11 @@ class AnalysisResult:
     # parity comparisons like n_shards/shard_seconds.
     wave2_overlap_seconds: float = 0.0
     wave2_overlapped: bool = False
+    # bytes the analysis moved between host and device ("d2h", "h2d"):
+    # the waves' uploads and read-backs, and the CR sample's. Telemetry,
+    # excluded from parity comparisons like shard_seconds.
+    copy_bytes: Dict[str, int] = dataclasses.field(
+        default_factory=new_copy_bytes)
 
     @property
     def conservative_cr(self) -> float:
@@ -401,26 +407,35 @@ class AnalysisPipeline:
         cfg = self.cfg
         a_ptr, a_idx = np.asarray(a.indptr), np.asarray(a.indices)
         b_ptr, b_idx = np.asarray(b.indptr), np.asarray(b.indices)
+        copies = new_copy_bytes()
         # Bucket both matrices onto the pow2 shape ladder so this single
         # fused launch (all three statistics stages, one dispatch, one
         # async D2H) reuses its jit specialization across matrices.
-        t0_w1 = time.perf_counter()
-        sa_ptr, sa_idx, ra_pad = _block_arrays(a_ptr, a_idx, 0, a.m)
-        sb_ptr, sb_idx, rb_pad = _block_arrays(b_ptr, b_idx, 0, b.m)
-        prod_p, lo_p, hi_p = _fused_stats(sa_ptr, sa_idx, sb_ptr, sb_idx,
-                                          num_rows_a=ra_pad,
-                                          num_rows_b=rb_pad)
-        wave1 = [Launch("stats", 0, (prod_p, lo_p, hi_p))]
-        start_async_host_copies(wave1)
-        trace.add_span("analysis.wave1", t0_w1,
-                       time.perf_counter() - t0_w1, fused=True)
+        with trace.span("analysis.wave1") as ws:
+            t0_w1 = time.perf_counter()
+            sa_ptr, sa_idx, ra_pad = _block_arrays(a_ptr, a_idx, 0, a.m)
+            sb_ptr, sb_idx, rb_pad = _block_arrays(b_ptr, b_idx, 0, b.m)
+            sa_ptr, sa_idx, sb_ptr, sb_idx = (
+                to_device(x, copies) for x in (sa_ptr, sa_idx, sb_ptr,
+                                               sb_idx))
+            prod_p, lo_p, hi_p = _fused_stats(sa_ptr, sa_idx, sb_ptr,
+                                              sb_idx, num_rows_a=ra_pad,
+                                              num_rows_b=rb_pad)
+            wave1 = [Launch("stats", 0, (prod_p, lo_p, hi_p))]
+            start_async_host_copies(wave1)
+            ws.measured(t0_w1, time.perf_counter() - t0_w1).set(fused=True)
         ov_s, ov_pending = 0.0, False
+        prod_row = None
         if overlap_work is not None:
             # The fused launch is dispatched but not awaited: the prework
             # runs behind whatever the backend still has in flight (it
             # blocks only on the products slice, which the work needs).
-            _, ov_s, ov_pending = overlap_host_work(
-                wave1, lambda: overlap_work(np.asarray(prod_p)[: a.m]))
+            def prework():
+                rows = to_host(prod_p, copies)[: a.m]
+                overlap_work(rows)
+                return rows
+
+            prod_row, ov_s, ov_pending = overlap_host_work(wave1, prework)
 
         def sketch_builder(m: int):
             key = (m, cfg.seed)
@@ -433,17 +448,20 @@ class AnalysisPipeline:
                 sketch_cache[key] = sk
             return sk, full
 
-        t0_w2 = time.perf_counter()
-        prod_row = np.asarray(prod_p)[: a.m]
-        out_lo = np.asarray(lo_p)[: a.m]
-        out_hi = np.asarray(hi_p)[: a.m]
-        trace.add_span("analysis.wave2", t0_w2, time.perf_counter() - t0_w2)
+        with trace.span("analysis.wave2") as ws:
+            t0_w2 = time.perf_counter()
+            if prod_row is None:
+                prod_row = to_host(prod_p, copies)[: a.m]
+            out_lo = to_host(lo_p, copies)[: a.m]
+            out_hi = to_host(hi_p, copies)[: a.m]
+            ws.measured(t0_w2, time.perf_counter() - t0_w2)
         return self._finish(
             a, b, prod_row=prod_row,
             out_lo=out_lo, out_hi=out_hi,
             build_sketches=build_sketches, sketch_builder=sketch_builder,
             n_shards=1, shard_seconds=None, known_sizes=known_sizes,
-            wave2_overlap_seconds=ov_s, wave2_overlapped=ov_pending)
+            wave2_overlap_seconds=ov_s, wave2_overlapped=ov_pending,
+            copies=copies)
 
     # -- device-partitioned path -------------------------------------------
 
@@ -458,6 +476,7 @@ class AnalysisPipeline:
         cfg = self.cfg
         n_dev = len(devs)
         shard_s = [0.0] * n_dev
+        copies = new_copy_bytes()
         a_ptr, a_idx = np.asarray(a.indptr), np.asarray(a.indices)
         b_ptr, b_idx = np.asarray(b.indptr), np.asarray(b.indices)
 
@@ -478,8 +497,8 @@ class AnalysisPipeline:
                 dev = devs[i]
                 parts.append(_ShardBlock(
                     index=i, device=dev, r0=r0, r1=r1,
-                    indptr=jax.device_put(sp, dev),
-                    indices=jax.device_put(si, dev), r_pad=r_pad))
+                    indptr=to_device(sp, copies, dev),
+                    indices=to_device(si, copies, dev), r_pad=r_pad))
                 shard_s[i] += time.perf_counter() - t0
             return parts
 
@@ -495,71 +514,72 @@ class AnalysisPipeline:
         # ---- wave 1: one fused launch per device slot holding both an
         # A-block (products) and its same-slot B-block (column ranges);
         # unpaired blocks fall back to the standalone stage jits ----
-        t0_w1 = time.perf_counter()
-        launches: List[Launch] = []
-        order = 0
-        fused1 = set()
-        for part in a_parts:
-            bpart = b_by.get(part.index)
-            t0 = time.perf_counter()
-            with device_context(part.device):
-                bp = jax.device_put(b_ptr_pad, part.device)
-                if bpart is not None:
-                    prod, mins, maxs = _fused_wave1(
-                        part.indptr, part.indices, bp,
-                        bpart.indptr, bpart.indices,
-                        num_rows_a=part.r_pad, num_rows_b=bpart.r_pad)
-                    launches.append(Launch(("w1", part, bpart), order,
-                                           (prod, mins, maxs)))
-                    fused1.add(part.index)
+        with trace.span("analysis.wave1") as ws:
+            t0_w1 = time.perf_counter()
+            launches: List[Launch] = []
+            order = 0
+            fused1 = set()
+            for part in a_parts:
+                bpart = b_by.get(part.index)
+                t0 = time.perf_counter()
+                with device_context(part.device):
+                    bp = to_device(b_ptr_pad, copies, part.device)
+                    if bpart is not None:
+                        prod, mins, maxs = _fused_wave1(
+                            part.indptr, part.indices, bp,
+                            bpart.indptr, bpart.indices,
+                            num_rows_a=part.r_pad, num_rows_b=bpart.r_pad)
+                        launches.append(Launch(("w1", part, bpart), order,
+                                               (prod, mins, maxs)))
+                        fused1.add(part.index)
+                    else:
+                        out = products_per_row(part.indptr, part.indices, bp,
+                                               num_rows_a=part.r_pad)
+                        launches.append(Launch(("prod", part, None), order,
+                                               (out,)))
+                order += 1
+                shard_s[part.index] += time.perf_counter() - t0
+            for part in b_parts:
+                if part.index in fused1:
+                    continue
+                t0 = time.perf_counter()
+                with device_context(part.device):
+                    mins, maxs = row_col_ranges(part.indptr, part.indices,
+                                                num_rows=part.r_pad)
+                launches.append(Launch(("brange", part, None), order,
+                                       (mins, maxs)))
+                order += 1
+                shard_s[part.index] += time.perf_counter() - t0
+            start_async_host_copies(launches)
+
+            prod_row = np.zeros(a.m, np.int32)
+            b_min = np.full(b.m, np.iinfo(np.int32).max, np.int32)
+            b_max = np.full(b.m, np.iinfo(np.int32).min, np.int32)
+
+            def fold_prod(part, arr):
+                # disjoint row blocks: per-block segment sums concatenate
+                prod_row[part.r0:part.r1] = arr[: part.rows]
+
+            def fold_brange(part, mn, mx):
+                np.minimum(b_min[part.r0:part.r1], mn[: part.rows],
+                           out=b_min[part.r0:part.r1])
+                np.maximum(b_max[part.r0:part.r1], mx[: part.rows],
+                           out=b_max[part.r0:part.r1])
+
+            for it in collect_in_completion_order(launches):
+                kind, part, bpart = it.tag
+                t0 = time.perf_counter()
+                host = [to_host(x, copies) for x in it.arrays]
+                if kind == "w1":
+                    fold_prod(part, host[0])
+                    fold_brange(bpart, host[1], host[2])
+                elif kind == "prod":
+                    fold_prod(part, host[0])
                 else:
-                    out = products_per_row(part.indptr, part.indices, bp,
-                                           num_rows_a=part.r_pad)
-                    launches.append(Launch(("prod", part, None), order,
-                                           (out,)))
-            order += 1
-            shard_s[part.index] += time.perf_counter() - t0
-        for part in b_parts:
-            if part.index in fused1:
-                continue
-            t0 = time.perf_counter()
-            with device_context(part.device):
-                mins, maxs = row_col_ranges(part.indptr, part.indices,
-                                            num_rows=part.r_pad)
-            launches.append(Launch(("brange", part, None), order,
-                                   (mins, maxs)))
-            order += 1
-            shard_s[part.index] += time.perf_counter() - t0
-        start_async_host_copies(launches)
-
-        prod_row = np.zeros(a.m, np.int32)
-        b_min = np.full(b.m, np.iinfo(np.int32).max, np.int32)
-        b_max = np.full(b.m, np.iinfo(np.int32).min, np.int32)
-
-        def fold_prod(part, arr):
-            # disjoint row blocks: per-block segment sums concatenate
-            prod_row[part.r0:part.r1] = arr[: part.rows]
-
-        def fold_brange(part, mn, mx):
-            np.minimum(b_min[part.r0:part.r1], mn[: part.rows],
-                       out=b_min[part.r0:part.r1])
-            np.maximum(b_max[part.r0:part.r1], mx[: part.rows],
-                       out=b_max[part.r0:part.r1])
-
-        for it in collect_in_completion_order(launches):
-            kind, part, bpart = it.tag
-            t0 = time.perf_counter()
-            host = [np.asarray(x) for x in it.arrays]
-            if kind == "w1":
-                fold_prod(part, host[0])
-                fold_brange(bpart, host[1], host[2])
-            elif kind == "prod":
-                fold_prod(part, host[0])
-            else:
-                fold_brange(part, host[0], host[1])
-            shard_s[part.index] += time.perf_counter() - t0
-        trace.add_span("analysis.wave1", t0_w1,
-                       time.perf_counter() - t0_w1, shards=n_dev)
+                    fold_brange(part, host[0], host[1])
+                shard_s[part.index] += time.perf_counter() - t0
+            ws.measured(t0_w1, time.perf_counter() - t0_w1).set(
+                shards=n_dev)
 
         total_products = int(prod_row.astype(np.int64).sum())
         er = total_products / max(a.nnz, 1)
@@ -580,79 +600,80 @@ class AnalysisPipeline:
         bmin_pad[: b.m] = b_min
         bmax_pad = np.full(rb_full, -1, np.int32)
         bmax_pad[: b.m] = b_max
-        t0_w2 = time.perf_counter()
-        launches = []
-        fused2 = set()
-        for part in a_parts:
-            bpart = b_by.get(part.index) if build_shard_sketches else None
-            t0 = time.perf_counter()
-            with device_context(part.device):
-                bmin_d = jax.device_put(bmin_pad, part.device)
-                bmax_d = jax.device_put(bmax_pad, part.device)
-                if bpart is not None:
-                    lo, hi, regs = _fused_wave2(
-                        part.indptr, part.indices, bmin_d, bmax_d,
-                        bpart.indptr, bpart.indices,
-                        num_rows_a=part.r_pad, num_rows_b=bpart.r_pad,
-                        m_regs=m_regs, seed=cfg.seed)
-                    launches.append(Launch(("w2", part, bpart), order,
-                                           (lo, hi, regs)))
-                    fused2.add(part.index)
-                else:
-                    lo, hi = output_col_ranges(part.indptr, part.indices,
-                                               bmin_d, bmax_d,
-                                               num_rows_a=part.r_pad)
-                    launches.append(Launch(("orange", part, None), order,
-                                           (lo, hi)))
-            order += 1
-            shard_s[part.index] += time.perf_counter() - t0
-        if build_shard_sketches:
-            for part in b_parts:
-                if part.index in fused2:
-                    continue
+        with trace.span("analysis.wave2") as ws:
+            t0_w2 = time.perf_counter()
+            launches = []
+            fused2 = set()
+            for part in a_parts:
+                bpart = b_by.get(part.index) if build_shard_sketches else None
                 t0 = time.perf_counter()
                 with device_context(part.device):
-                    regs = hll.build_sketches(
-                        part.indptr, part.indices, m_regs=m_regs,
-                        num_rows=part.r_pad, seed=cfg.seed)
-                launches.append(Launch(("sketch", part, None), order,
-                                       (regs,)))
+                    bmin_d = to_device(bmin_pad, copies, part.device)
+                    bmax_d = to_device(bmax_pad, copies, part.device)
+                    if bpart is not None:
+                        lo, hi, regs = _fused_wave2(
+                            part.indptr, part.indices, bmin_d, bmax_d,
+                            bpart.indptr, bpart.indices,
+                            num_rows_a=part.r_pad, num_rows_b=bpart.r_pad,
+                            m_regs=m_regs, seed=cfg.seed)
+                        launches.append(Launch(("w2", part, bpart), order,
+                                               (lo, hi, regs)))
+                        fused2.add(part.index)
+                    else:
+                        lo, hi = output_col_ranges(part.indptr, part.indices,
+                                                   bmin_d, bmax_d,
+                                                   num_rows_a=part.r_pad)
+                        launches.append(Launch(("orange", part, None), order,
+                                               (lo, hi)))
                 order += 1
                 shard_s[part.index] += time.perf_counter() - t0
-        start_async_host_copies(launches)
+            if build_shard_sketches:
+                for part in b_parts:
+                    if part.index in fused2:
+                        continue
+                    t0 = time.perf_counter()
+                    with device_context(part.device):
+                        regs = hll.build_sketches(
+                            part.indptr, part.indices, m_regs=m_regs,
+                            num_rows=part.r_pad, seed=cfg.seed)
+                    launches.append(Launch(("sketch", part, None), order,
+                                           (regs,)))
+                    order += 1
+                    shard_s[part.index] += time.perf_counter() - t0
+            start_async_host_copies(launches)
 
-        # Caller-provided host prework (planner binning) rides behind the
-        # in-flight wave-2 launches; it consumes only the wave-1 merged
-        # products, which are already host-resident here.
-        ov_s, ov_pending = 0.0, False
-        if overlap_work is not None:
-            _, ov_s, ov_pending = overlap_host_work(
-                launches, lambda: overlap_work(prod_row))
+            # Caller-provided host prework (planner binning) rides behind the
+            # in-flight wave-2 launches; it consumes only the wave-1 merged
+            # products, which are already host-resident here.
+            ov_s, ov_pending = 0.0, False
+            if overlap_work is not None:
+                _, ov_s, ov_pending = overlap_host_work(
+                    launches, lambda: overlap_work(prod_row))
 
-        out_lo = np.full(a.m, np.iinfo(np.int32).max, np.int32)
-        out_hi = np.full(a.m, np.iinfo(np.int32).min, np.int32)
-        sketch_parts: List[Tuple[int, int, np.ndarray]] = []
+            out_lo = np.full(a.m, np.iinfo(np.int32).max, np.int32)
+            out_hi = np.full(a.m, np.iinfo(np.int32).min, np.int32)
+            sketch_parts: List[Tuple[int, int, np.ndarray]] = []
 
-        def fold_orange(part, lo, hi):
-            np.minimum(out_lo[part.r0:part.r1], lo[: part.rows],
-                       out=out_lo[part.r0:part.r1])
-            np.maximum(out_hi[part.r0:part.r1], hi[: part.rows],
-                       out=out_hi[part.r0:part.r1])
+            def fold_orange(part, lo, hi):
+                np.minimum(out_lo[part.r0:part.r1], lo[: part.rows],
+                           out=out_lo[part.r0:part.r1])
+                np.maximum(out_hi[part.r0:part.r1], hi[: part.rows],
+                           out=out_hi[part.r0:part.r1])
 
-        for it in collect_in_completion_order(launches):
-            kind, part, bpart = it.tag
-            t0 = time.perf_counter()
-            host = [np.asarray(x) for x in it.arrays]
-            if kind == "w2":
-                fold_orange(part, host[0], host[1])
-                sketch_parts.append((bpart.r0, bpart.r1, host[2]))
-            elif kind == "orange":
-                fold_orange(part, host[0], host[1])
-            else:
-                sketch_parts.append((part.r0, part.r1, host[0]))
-            shard_s[part.index] += time.perf_counter() - t0
-        trace.add_span("analysis.wave2", t0_w2,
-                       time.perf_counter() - t0_w2, shards=n_dev)
+            for it in collect_in_completion_order(launches):
+                kind, part, bpart = it.tag
+                t0 = time.perf_counter()
+                host = [to_host(x, copies) for x in it.arrays]
+                if kind == "w2":
+                    fold_orange(part, host[0], host[1])
+                    sketch_parts.append((bpart.r0, bpart.r1, host[2]))
+                elif kind == "orange":
+                    fold_orange(part, host[0], host[1])
+                else:
+                    sketch_parts.append((part.r0, part.r1, host[0]))
+                shard_s[part.index] += time.perf_counter() - t0
+            ws.measured(t0_w2, time.perf_counter() - t0_w2).set(
+                shards=n_dev)
 
         def sketch_builder(m: int):
             if cached_sk is not None:
@@ -662,7 +683,7 @@ class AnalysisPipeline:
                 "sketches — _needs_sketches gates must agree"
             merged = hll.merge_register_partials(sketch_parts, num_rows=b.m,
                                                  m_regs=m)
-            sk = jnp.asarray(merged)
+            sk = to_device(merged, copies)
             if sketch_cache is not None:
                 sketch_cache[(m, cfg.seed)] = sk
             return sk, None
@@ -671,7 +692,8 @@ class AnalysisPipeline:
             a, b, prod_row=prod_row, out_lo=out_lo, out_hi=out_hi,
             build_sketches=build_sketches, sketch_builder=sketch_builder,
             n_shards=n_dev, shard_seconds=shard_s, known_sizes=known_sizes,
-            wave2_overlap_seconds=ov_s, wave2_overlapped=ov_pending)
+            wave2_overlap_seconds=ov_s, wave2_overlapped=ov_pending,
+            copies=copies)
 
     # -- shared host tail: workflow gate + sampled CR ----------------------
 
@@ -681,8 +703,10 @@ class AnalysisPipeline:
                 shard_seconds: Optional[List[float]],
                 known_sizes: Optional[np.ndarray] = None,
                 wave2_overlap_seconds: float = 0.0,
-                wave2_overlapped: bool = False) -> AnalysisResult:
+                wave2_overlapped: bool = False,
+                copies: Optional[Dict[str, int]] = None) -> AnalysisResult:
         cfg = self.cfg
+        copies = copies if copies is not None else new_copy_bytes()
         total_products = int(np.asarray(prod_row, np.int64).sum())
         nnz_a, nnz_b = a.nnz, b.nnz
         er = total_products / max(nnz_a, 1)
@@ -704,7 +728,7 @@ class AnalysisPipeline:
                 n_shards=n_shards, shard_seconds=shard_seconds,
                 known_sizes=known_sizes,
                 wave2_overlap_seconds=wave2_overlap_seconds,
-                wave2_overlapped=wave2_overlapped)
+                wave2_overlapped=wave2_overlapped, copy_bytes=copies)
 
         if nproducts_avg < cfg.upper_bound_avg_products:
             return AnalysisResult(
@@ -715,7 +739,7 @@ class AnalysisPipeline:
                 workflow="upper_bound", cr_sigma=cfg.cr_sigma,
                 n_shards=n_shards, shard_seconds=shard_seconds,
                 wave2_overlap_seconds=wave2_overlap_seconds,
-                wave2_overlapped=wave2_overlapped)
+                wave2_overlapped=wave2_overlapped, copy_bytes=copies)
 
         sketches = None
         sampled_cr = cr_mean = cr_std = None
@@ -744,10 +768,11 @@ class AnalysisPipeline:
                 overlap_host_work(in_flight, _sample_prework)
             wave2_overlap_seconds += est_s
             wave2_overlapped = wave2_overlapped or est_pend
-            merged = hll.merge_sketches(sp, si, sk_padded,
+            merged = hll.merge_sketches(to_device(sp, copies),
+                                        to_device(si, copies), sk_padded,
                                         num_rows_a=r_pad)
             est = hll.estimate_cardinality(merged, clip_max=b.n)
-            est = np.maximum(np.asarray(est)[: len(sample_rows)], 1.0)
+            est = np.maximum(to_host(est, copies)[: len(sample_rows)], 1.0)
             prods = np.asarray(prod_row)[sample_rows].astype(np.float64)
             mask = prods > 0
             if mask.any():
@@ -773,7 +798,7 @@ class AnalysisPipeline:
             cr_sigma=cfg.cr_sigma, n_shards=n_shards,
             shard_seconds=shard_seconds,
             wave2_overlap_seconds=wave2_overlap_seconds,
-            wave2_overlapped=wave2_overlapped)
+            wave2_overlapped=wave2_overlapped, copy_bytes=copies)
 
 
 def analyze(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(),
@@ -807,7 +832,9 @@ def analyze(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(),
 
 def sharded_merge_estimate(a: CSR, sketches_with_sentinel,
                            *, clip_max: Optional[int] = None,
-                           devices: DeviceSpec = None) -> np.ndarray:
+                           devices: DeviceSpec = None,
+                           copies: Optional[Dict[str, int]] = None
+                           ) -> np.ndarray:
     """Device-partitioned ``kernels.ops.merge_estimate_op`` (prediction
     stage): per-row HLL output-size estimates for C = A @ B.
 
@@ -819,9 +846,11 @@ def sharded_merge_estimate(a: CSR, sketches_with_sentinel,
     to the all-zero sentinel sketch), so the sharded result is
     bit-identical to the monolithic one at any shard count. Block shapes
     ride the same pow2 ladders as the sharded analysis stages, bounding
-    jit specializations across splits and topologies.
+    jit specializations across splits and topologies. ``copies`` counts
+    the blocks' uploads and the estimates' read-backs.
     """
     from repro.kernels import ops as kops
+    copies = copies if copies is not None else new_copy_bytes()
     devs = resolve_devices(devices) if devices is not None else None
     if devs is not None and (len(devs) <= 1 or a.m == 0):
         devs = None
@@ -830,14 +859,14 @@ def sharded_merge_estimate(a: CSR, sketches_with_sentinel,
         # Single-device merges ride the same pow2 block bucket as shards
         # so the merge/estimate specialization is matrix-independent.
         sp, si, r_pad = _block_arrays(a_ptr, a_idx, 0, a.m)
-        sub = CSR(jnp.asarray(sp), jnp.asarray(si),
+        sub = CSR(to_device(sp, copies), to_device(si, copies),
                   jnp.zeros((si.shape[0],), jnp.float32),
                   (r_pad, a.n), int(sp[-1]))
         _, est = kops.merge_estimate_op(sub, sketches_with_sentinel,
                                         clip_max=clip_max)
-        return np.asarray(est)[: a.m]
+        return to_host(est, copies)[: a.m]
     blocks = contiguous_split_rows(a_ptr, len(devs))
-    sk_host = np.asarray(sketches_with_sentinel)
+    sk_host = to_host(sketches_with_sentinel, copies)
     launches: List[Launch] = []
     order = 0
     for i, (r0, r1) in enumerate(blocks):
@@ -846,10 +875,10 @@ def sharded_merge_estimate(a: CSR, sketches_with_sentinel,
         sp, si, r_pad = _block_arrays(a_ptr, a_idx, r0, r1)
         dev = devs[i]
         with device_context(dev):
-            sub = CSR(jax.device_put(sp, dev), jax.device_put(si, dev),
+            sub = CSR(to_device(sp, copies, dev), to_device(si, copies, dev),
                       jnp.zeros((si.shape[0],), jnp.float32),
                       (r_pad, a.n), int(sp[-1]))
-            sk_d = jax.device_put(sk_host, dev)
+            sk_d = to_device(sk_host, copies, dev)
             _, est = kops.merge_estimate_op(sub, sk_d, clip_max=clip_max)
         launches.append(Launch((r0, r1), order, (est,)))
         order += 1
@@ -857,7 +886,7 @@ def sharded_merge_estimate(a: CSR, sketches_with_sentinel,
     out = np.zeros(a.m, np.float32)
     for it in collect_in_completion_order(launches):
         r0, r1 = it.tag
-        out[r0:r1] = np.asarray(it.arrays[0])[: r1 - r0]
+        out[r0:r1] = to_host(it.arrays[0], copies)[: r1 - r0]
     return out
 
 

@@ -9,9 +9,11 @@ the host can use it. This module is the repo-wide home for it; the
 numeric executor (``core.executor``) and the sharded analysis pipeline
 (``core.analysis.AnalysisPipeline``) both dispatch through these helpers.
 
-Device-set plumbing (``resolve_devices``/``topology_key``) lives here too
-so stages below the partitioner (e.g. analysis) can normalize device
-specs without importing ``core.partition`` (which depends on the plan
+The byte counts of host<->device copies (:func:`to_host`,
+:func:`to_device`, feeding ``OceanReport.copy_bytes``) live here too, as
+does the device-set plumbing (``resolve_devices``/``topology_key``), so
+stages below the partitioner (e.g. analysis) can normalize device specs
+without importing ``core.partition`` (which depends on the plan
 containers); ``core.partition`` re-exports them unchanged.
 """
 from __future__ import annotations
@@ -19,7 +21,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
-from typing import Callable, Iterator, List, Sequence, Tuple, Union
+from typing import Callable, Dict, Iterator, List, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -56,6 +58,35 @@ def topology_key(devices: Sequence) -> str:
     """Stable string identity of an ordered device set — the extra
     component plan caches key sharded plans by."""
     return ",".join(f"{d.platform}:{d.id}" for d in devices)
+
+
+def new_copy_bytes() -> Dict[str, int]:
+    """An empty count of one call's copies, by direction."""
+    return {"d2h": 0, "h2d": 0}
+
+
+def to_host(x, copies: Dict[str, int]) -> np.ndarray:
+    """``np.asarray(x)``, adding to ``copies["d2h"]`` the bytes that cross.
+
+    A device array crosses on its first read only: ``jax.Array`` keeps the
+    host copy (``_npy_value``, where the backend had to copy), so a later
+    read of the same array costs nothing and counts nothing. A host array
+    counts nothing."""
+    if isinstance(x, jax.Array) and getattr(x, "_npy_value", None) is None:
+        copies["d2h"] += x.nbytes
+    return np.asarray(x)
+
+
+def to_device(x, copies: Dict[str, int], device=None) -> jax.Array:
+    """Commit ``x`` to ``device`` (the default device when None), adding
+    to ``copies["h2d"]`` the bytes of a host array in the dtype it lands
+    in (64-bit types narrow unless x64 is on); a device array is moved, if
+    at all, between devices and counts nothing."""
+    if not isinstance(x, jax.Array):
+        x = np.asarray(x)
+        copies["h2d"] += x.size * jax.dtypes.canonicalize_dtype(
+            x.dtype).itemsize
+    return jax.device_put(x, device)
 
 
 @dataclasses.dataclass
@@ -113,10 +144,11 @@ def overlap_host_work(launches: Sequence[Launch],
     degenerate case).
     """
     pending = any(not launch_ready(it) for it in launches)
-    t0 = time.perf_counter()
-    result = work()
-    dt = time.perf_counter() - t0
-    trace.add_span("dispatch.overlap_host_work", t0, dt, overlapped=pending)
+    with trace.span("dispatch.overlap_host_work") as sp:
+        t0 = time.perf_counter()
+        result = work()
+        dt = time.perf_counter() - t0
+        sp.measured(t0, dt).set(overlapped=pending)
     return result, dt, pending
 
 

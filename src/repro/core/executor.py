@@ -63,12 +63,13 @@ from repro.obs import accuracy as obs_accuracy
 from repro.obs import trace
 from . import esc as esc_mod
 from .dispatch import (Launch, collect_in_completion_order, device_context,
-                       start_async_host_copies)
+                       new_copy_bytes, start_async_host_copies, to_device,
+                       to_host)
 from .esc import EscOverflowError
 from .formats import (CSR, PAD_COL, csr_from_arrays, csr_rows_to_ell,
-                      pow2_at_least)
+                      flat_gather_index, pow2_at_least)
 from .planner import (DenseBinExec, EscExec, ExecutionPlan, HashBinExec,
-                      OceanReport, gather_rows)
+                      OceanReport)
 
 SERIAL = "serial"
 PIPELINED = "pipelined"
@@ -188,31 +189,44 @@ def _filter_slab(slab: _Slab, post: MergePostOps
 
 
 def _esc_to_slab(res, rows: np.ndarray, num_rows: int,
-                 out_cap: int) -> Tuple[_Slab, int]:
-    """Convert an ESCResult over a row subset into a slab."""
-    nnz = esc_mod.ensure_esc_capacity(res.nnz, out_cap, where="ESC shard")
+                 out_cap: int, copies: Dict[str, int]) -> Tuple[_Slab, int]:
+    """Convert an ESCResult over a row subset, on the device, into a slab
+    on the host: the count and the row pointers come down, the rows are
+    laid out as ELL on the device, and the ELL blocks come down."""
+    nnz = esc_mod.ensure_esc_capacity(to_host(res.nnz, copies), out_cap,
+                                      where="ESC shard")
     # shape-bucketed ESC shards carry inert pad rows past num_rows (zero
     # counts by construction); slice them off before slab assembly
-    counts = np.asarray(res.indptr[1:] - res.indptr[:-1])[:num_rows]
+    ptr = to_host(res.indptr, copies).astype(np.int64)
+    counts = (ptr[1:] - ptr[:-1])[:num_rows]
     width = int(counts.max()) if len(counts) else 1
     width = max(width, 1)
     ell_i, ell_v = csr_rows_to_ell(res.indptr, res.indices, res.values,
                                    num_rows=num_rows, ell_width=width,
                                    pad_index=int(PAD_COL))
-    return _Slab(rows, np.asarray(ell_i), np.asarray(ell_v),
-                 counts.astype(np.int64)), nnz
+    return _Slab(rows, to_host(ell_i, copies), to_host(ell_v, copies),
+                 counts), nnz
 
 
-def _gather_ell_values(exec_, a_values: np.ndarray) -> jax.Array:
+def _upload_csr(indptr: np.ndarray, indices: np.ndarray, values: np.ndarray,
+                shape: Tuple[int, int], copies: Dict[str, int]) -> CSR:
+    """``csr_from_arrays`` of host arrays, counting the bytes that land."""
+    c = csr_from_arrays(indptr, indices, values, shape)
+    copies["h2d"] += c.indptr.nbytes + c.indices.nbytes + c.values.nbytes
+    return c
+
+
+def _gather_ell_values(exec_, a_values: np.ndarray,
+                       copies: Dict[str, int]) -> jax.Array:
     """Value half of ELL bin input prep, shared by the dense and hash bin
     runners: replay the bin's frozen flat-gather map over (possibly new)
     A values and commit the ELL block."""
-    return jax.numpy.asarray(
-        kops.gather_bin_values(a_values, exec_.pos, exec_.valid))
+    return to_device(kops.gather_bin_values(a_values, exec_.pos,
+                                            exec_.valid), copies)
 
 
 def _prep_shard_b(b: CSR, b_cols_host, b_vals_host, shard: "_ShardWork",
-                  multi: bool):
+                  multi: bool, copies: Dict[str, int]):
     """Per-shard B-side inputs shared by every bin family: the padded
     flat arrays the dense/hash kernels stream (shipped to the shard's
     device when more than one shard participates) plus the raw CSR
@@ -220,16 +234,16 @@ def _prep_shard_b(b: CSR, b_cols_host, b_vals_host, shard: "_ShardWork",
     actually has an ESC bin — ``None`` means "use host arrays")."""
     if not (multi and shard.device is not None):
         return b_cols_host, b_vals_host, None
-    b_cols_pad = jax.device_put(b_cols_host, shard.device)
-    b_vals_pad = jax.device_put(b_vals_host, shard.device)
-    b_esc = (tuple(jax.device_put(x, shard.device)
+    b_cols_pad = to_device(b_cols_host, copies, shard.device)
+    b_vals_pad = to_device(b_vals_host, copies, shard.device)
+    b_esc = (tuple(to_device(x, copies, shard.device)
                    for x in (b.indptr, b.indices, b.values))
              if shard.esc is not None else None)
     return b_cols_pad, b_vals_pad, b_esc
 
 
 def _run_dense_bin(be: DenseBinExec, a_values: np.ndarray, b_cols_pad,
-                   b_vals_pad):
+                   b_vals_pad, copies: Dict[str, int]):
     """Dispatch one dense bin; returns device arrays (cols, vals, nnz).
 
     Results are per-row independent, so any row subset of a bin produces
@@ -240,7 +254,7 @@ def _run_dense_bin(be: DenseBinExec, a_values: np.ndarray, b_cols_pad,
     a pure function of (bin, rung)) so every same-rung slice of one bin
     replays a single jit specialization.
     """
-    a_vals = _gather_ell_values(be, a_values)
+    a_vals = _gather_ell_values(be, a_values, copies)
     return kops.dense_bin_op(
         be.a_rows, a_vals, be.a_starts, be.a_lens, be.row_lo,
         b_cols_pad, b_vals_pad, window=be.window,
@@ -248,7 +262,7 @@ def _run_dense_bin(be: DenseBinExec, a_values: np.ndarray, b_cols_pad,
 
 
 def _run_hash_bin(hb: HashBinExec, a_values: np.ndarray, b_cols_pad,
-                  b_vals_pad):
+                  b_vals_pad, copies: Dict[str, int]):
     """Dispatch one hash bin; returns device arrays (cols, vals, nnz).
 
     Same per-row-independence contract as dense bins: each row owns its
@@ -257,14 +271,15 @@ def _run_hash_bin(hb: HashBinExec, a_values: np.ndarray, b_cols_pad,
     the XLA path — so any row subset replays one jit specialization and
     produces the full bin's per-row output bit for bit.
     """
-    a_vals = _gather_ell_values(hb, a_values)
+    a_vals = _gather_ell_values(hb, a_values, copies)
     return kops.hash_bin_op(
         hb.a_rows, a_vals, hb.a_starts, hb.a_lens, b_cols_pad, b_vals_pad,
         table=hb.table, spill=hb.spill, p_cap=hb.p_cap,
         f_chunk=hb.f_chunk, tile=hb.tile)
 
 
-def _run_esc_bin(ex: EscExec, a_values: np.ndarray, b: CSR, *,
+def _run_esc_bin(ex: EscExec, a_values: np.ndarray, b: CSR,
+                 copies: Dict[str, int], *,
                  b_arrays: Optional[Tuple] = None):
     """Dispatch the ESC bin; returns the (device-side) ESCResult.
 
@@ -278,14 +293,16 @@ def _run_esc_bin(ex: EscExec, a_values: np.ndarray, b: CSR, *,
         b_arrays if b_arrays is not None else (b.indptr, b.indices,
                                                b.values))
     return esc_mod.esc_spgemm(
-        ex.sub_indptr, ex.sub_indices, a_values[ex.src],
+        *(to_device(x, copies) for x in (ex.sub_indptr, ex.sub_indices,
+                                         a_values[ex.src])),
         b_indptr, b_indices, b_values, p_cap=ex.p_cap,
         out_cap=ex.out_cap, num_rows_a=ex.sub_indptr.shape[0] - 1)
 
 
 def _compact_slabs(slabs: List[_Slab], shape: Tuple[int, int],
-                   dtype) -> Tuple[CSR, int]:
-    """Scatter row-disjoint slabs into one CSR (order-independent)."""
+                   dtype, copies: Dict[str, int]) -> Tuple[CSR, int]:
+    """Scatter row-disjoint slabs into one CSR (order-independent) and
+    upload it."""
     m = shape[0]
     counts = np.zeros(m, np.int64)
     for s in slabs:
@@ -305,7 +322,7 @@ def _compact_slabs(slabs: List[_Slab], shape: Tuple[int, int],
         pos = indptr[s.rows][:, None] + slot
         out_cols[pos[valid]] = s.cols[valid]
         out_vals[pos[valid]] = s.vals[valid]
-    return csr_from_arrays(indptr, out_cols, out_vals, shape), total
+    return _upload_csr(indptr, out_cols, out_vals, shape, copies), total
 
 
 # ---------------------------------------------------------------------------
@@ -328,14 +345,15 @@ def _shards_of_plan(plan: ExecutionPlan) -> List[_ShardWork]:
 
 
 def _dispatch(shards: List[_ShardWork], a_values: np.ndarray,
-              b: CSR) -> List[Launch]:
+              b: CSR, copies: Dict[str, int]) -> List[Launch]:
     """Dispatch stage: enqueue every (shard, bin) launch without blocking.
 
     B is padded once on the host and shipped to each shard's device when
     more than one shard participates. Async D2H copies are started for
     every result so the collect stage overlaps transfers with compute.
     Each launch is tagged ``(kind, exec)`` so the merge can tell dense
-    slabs (overflow-scanned) from ESC slabs (capacities are upper bounds).
+    slabs (overflow-scanned) from ESC slabs (capacities are upper bounds);
+    an ESC launch's ``exec`` is ``(EscExec, ESCResult)``.
     """
     items: List[Launch] = []
     order = 0
@@ -346,36 +364,41 @@ def _dispatch(shards: List[_ShardWork], a_values: np.ndarray,
             continue
         with device_context(shard.device):
             b_cols_pad, b_vals_pad, b_esc = _prep_shard_b(
-                b, b_cols_host, b_vals_host, shard, multi)
+                b, b_cols_host, b_vals_host, shard, multi, copies)
             for be in shard.dense:
-                arrays = _run_dense_bin(be, a_values, b_cols_pad, b_vals_pad)
+                arrays = _run_dense_bin(be, a_values, b_cols_pad, b_vals_pad,
+                                        copies)
                 items.append(Launch(("dense", be), order, tuple(arrays)))
                 order += 1
             for hb in shard.hash:
-                arrays = _run_hash_bin(hb, a_values, b_cols_pad, b_vals_pad)
+                arrays = _run_hash_bin(hb, a_values, b_cols_pad, b_vals_pad,
+                                       copies)
                 items.append(Launch(("hash", hb), order, tuple(arrays)))
                 order += 1
             if shard.esc is not None:
-                res = _run_esc_bin(shard.esc, a_values, b, b_arrays=b_esc)
-                items.append(Launch(("esc", shard.esc), order, tuple(res)))
+                res = _run_esc_bin(shard.esc, a_values, b, copies,
+                                   b_arrays=b_esc)
+                # only the count and the row pointers come down whole:
+                # _esc_to_slab lays the rows out on the device first
+                items.append(Launch(("esc", (shard.esc, res)), order,
+                                    (res.nnz, res.indptr)))
                 order += 1
     start_async_host_copies(items)
     return items
 
 
-def _materialize(it: Launch) -> _Slab:
+def _materialize(it: Launch, copies: Dict[str, int]) -> _Slab:
     """Pull one pending launch to the host (blocks only on this item) and
     shape it as a slab, dropping any shape-bucketing pad rows."""
     kind, exec_ = it.tag
     if kind in ("dense", "hash"):
         be = exec_
         nv = be.n_valid
-        cols, vals, nnz = (np.asarray(x) for x in it.arrays)
+        cols, vals, nnz = (to_host(x, copies) for x in it.arrays)
         return _Slab(be.rows, cols[:nv], vals[:nv],
                      nnz[:nv].astype(np.int64))
-    ex: EscExec = exec_
-    res = esc_mod.ESCResult(*(np.asarray(x) for x in it.arrays))
-    slab, _ = _esc_to_slab(res, ex.rows, len(ex.rows), ex.out_cap)
+    ex, res = exec_
+    slab, _ = _esc_to_slab(res, ex.rows, len(ex.rows), ex.out_cap, copies)
     return slab
 
 
@@ -480,25 +503,31 @@ class _MergeState:
 
 
 def _run_overflow_fallback(state: _MergeState, products: np.ndarray,
-                           a: CSR, b: CSR) -> int:
+                           a: CSR, b: CSR, a_values: np.ndarray,
+                           copies: Dict[str, int]) -> Tuple[int, float]:
     """Re-run overflowed rows through the exact ESC pass (paper §3.2).
 
     One global pass over all overflow rows; per-row results are independent
     of how rows were grouped, so this matches the serial path bit for bit.
+    Returns the rows re-run and the pass's seconds (0 without overflow).
     """
     rows = state.fallback_rows()
     if rows is None:
-        return 0
+        return 0, 0.0
     with trace.span("exec.overflow_fallback") as sp:
-        sub = gather_rows(a, rows)
+        t0 = time.perf_counter()
+        new_ptr, src = flat_gather_index(to_host(a.indptr, copies), rows)
+        sub = _upload_csr(new_ptr, to_host(a.indices, copies)[src],
+                          a_values[src], (len(rows), a.n), copies)
         p_cap = pow2_at_least(int(products[rows].sum()), floor=64)
         res = esc_mod.esc_spgemm(
             sub.indptr, sub.indices, sub.values, b.indptr, b.indices,
             b.values, p_cap=p_cap, out_cap=p_cap, num_rows_a=sub.m)
-        slab, _ = _esc_to_slab(res, rows, sub.m, p_cap)
+        slab, _ = _esc_to_slab(res, rows, sub.m, p_cap, copies)
         state.add_fallback(slab)
-        sp.set(rows=len(rows))
-    return len(rows)
+        dt = time.perf_counter() - t0
+        sp.measured(t0, dt).set(rows=len(rows))
+    return len(rows), dt
 
 
 # ---------------------------------------------------------------------------
@@ -507,53 +536,82 @@ def _run_overflow_fallback(state: _MergeState, products: np.ndarray,
 
 def _collect_serial(items: List[Launch], plan: ExecutionPlan, a: CSR,
                     b: CSR, a_values: np.ndarray, stage: Dict[str, float],
-                    dispatch_s: float, post: Optional[MergePostOps]):
+                    dispatch_s: float, post: Optional[MergePostOps],
+                    copies: Dict[str, int]):
     """Reference semantics: one global barrier, then merge. Keeps the
     legacy stage keys (numeric/overflow/postprocess)."""
-    t0 = time.perf_counter()
     state = _MergeState(a.m, post)
-    slabs = [(it, _materialize(it)) for it in items]
-    stage["numeric"] = dispatch_s + (time.perf_counter() - t0)
-    trace.add_span("exec.collect", t0, time.perf_counter() - t0)
-    t0 = time.perf_counter()
-    for it, slab in slabs:
-        state.add(it, slab)
-    n_overflow = _run_overflow_fallback(state, plan.products, a, b)
+    with trace.span("exec.collect") as sp:
+        t0 = time.perf_counter()
+        slabs = [(it, _materialize(it, copies)) for it in items]
+        dt = time.perf_counter() - t0
+        sp.measured(t0, dt)
+    stage["numeric"] = dispatch_s + dt
+    with trace.span("exec.merge") as sp:
+        t0 = time.perf_counter()
+        for it, slab in slabs:
+            state.add(it, slab)
+        sp.measured(t0, time.perf_counter() - t0)
+    n_overflow, stage["fallback"] = _run_overflow_fallback(
+        state, plan.products, a, b, a_values, copies)
     stage["overflow"] = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    c, total = _compact_slabs(state.finalize(), (a.m, b.n), a_values.dtype)
-    stage["postprocess"] = time.perf_counter() - t0
-    trace.add_span("exec.compact", t0, stage["postprocess"])
+    with trace.span("exec.compact") as sp:
+        t0 = time.perf_counter()
+        c, total = _compact_slabs(state.finalize(), (a.m, b.n),
+                                  a_values.dtype, copies)
+        stage["postprocess"] = time.perf_counter() - t0
+        sp.measured(t0, stage["postprocess"])
     return (c, total, n_overflow, 0.0, 0.0, state.raw_counts,
             state.overflow_causes)
+
+
+def _finish_merge(state: _MergeState, plan: ExecutionPlan, a: CSR, b: CSR,
+                  a_values: np.ndarray, stage: Dict[str, float],
+                  copies: Dict[str, int]) -> Tuple[CSR, int, int, float]:
+    """The merge's tail once every slab is in: the overflow fallback
+    (``stage["fallback"]``) and the compaction. Returns C, its nnz, the
+    rows re-run and the tail's seconds, which belong to the merge."""
+    t0 = time.perf_counter()
+    n_overflow, stage["fallback"] = _run_overflow_fallback(
+        state, plan.products, a, b, a_values, copies)
+    with trace.span("exec.compact") as sp:
+        t1 = time.perf_counter()
+        c, total = _compact_slabs(state.finalize(), (a.m, b.n),
+                                  a_values.dtype, copies)
+        t2 = time.perf_counter()
+        sp.measured(t1, t2 - t1)
+    return c, total, n_overflow, t2 - t0
 
 
 def _collect_pipelined(items: List[Launch], plan: ExecutionPlan, a: CSR,
                        b: CSR, a_values: np.ndarray,
                        stage: Dict[str, float], dispatch_s: float,
-                       post: Optional[MergePostOps]):
+                       post: Optional[MergePostOps],
+                       copies: Dict[str, int]):
     """Overlapped collect/merge: slabs are pulled in completion order and
     each one's overflow scan + fused post-ops + count accumulation runs
     while later slabs are still being computed or copied back."""
     state = _MergeState(a.m, post)
     collect_s = merge_s = overlap_s = 0.0
     n_left = len(items)
-    traced = trace.enabled()   # hot loop: no span/attr allocation when off
+    traced = trace.enabled()   # hot loop: no attr allocation when off
     for it in collect_in_completion_order(items):
         n_left -= 1
-        t0 = time.perf_counter()
-        slab = _materialize(it)
-        dt_c = time.perf_counter() - t0
+        with trace.span("exec.collect") as sp:
+            t0 = time.perf_counter()
+            slab = _materialize(it, copies)
+            dt_c = time.perf_counter() - t0
+            sp.measured(t0, dt_c)
+            if traced:
+                sp.set(order=it.order, kind=it.tag[0])
         collect_s += dt_c
-        if traced:
-            trace.add_span("exec.collect", t0, dt_c, order=it.order,
-                           kind=it.tag[0])
-        t0 = time.perf_counter()
-        state.add(it, slab)
-        dt = time.perf_counter() - t0
-        if traced:
-            trace.add_span("exec.merge", t0, dt, order=it.order,
-                           overlapped=bool(n_left))
+        with trace.span("exec.merge") as sp:
+            t0 = time.perf_counter()
+            state.add(it, slab)
+            dt = time.perf_counter() - t0
+            sp.measured(t0, dt)
+            if traced:
+                sp.set(order=it.order, overlapped=bool(n_left))
         merge_s += dt
         if n_left:
             # merge work done before the last slab was collected — the
@@ -561,13 +619,9 @@ def _collect_pipelined(items: List[Launch], plan: ExecutionPlan, a: CSR,
             # on async backends the outstanding items are still computing
             # or copying while this chunk executes
             overlap_s += dt
-    t0 = time.perf_counter()
-    n_overflow = _run_overflow_fallback(state, plan.products, a, b)
-    t1 = time.perf_counter()
-    c, total = _compact_slabs(state.finalize(), (a.m, b.n), a_values.dtype)
-    t2 = time.perf_counter()
-    trace.add_span("exec.compact", t1, t2 - t1)
-    merge_s += t2 - t0
+    c, total, n_overflow, tail_s = _finish_merge(state, plan, a, b,
+                                                 a_values, stage, copies)
+    merge_s += tail_s
     stage["dispatch"] = dispatch_s
     stage["collect"] = collect_s
     stage["merge"] = merge_s
@@ -579,7 +633,8 @@ def _collect_pipelined(items: List[Launch], plan: ExecutionPlan, a: CSR,
 def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
                       b: CSR, a_values: np.ndarray,
                       stage: Dict[str, float], dispatch_s: float,
-                      post: Optional[MergePostOps]):
+                      post: Optional[MergePostOps],
+                      copies: Dict[str, int]):
     """Collect with a dedicated merge worker thread.
 
     The main thread runs the collect loop (completion-order pull +
@@ -590,7 +645,8 @@ def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
     polls). Bit-identity holds because the worker is the sole mutator of
     the merge state and ``_MergeState`` is add-order-independent; the
     overflow fallback and final scatter run on the main thread after the
-    worker drains.
+    worker drains. The worker opens its ``exec.merge_worker`` spans on its
+    own thread.
 
     ``overlap_s`` sums the portions of worker merge spans that ran
     before the collect loop finished — merge work a single-threaded
@@ -600,37 +656,39 @@ def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
     slabs: "queue.Queue[Optional[Tuple[Launch, _Slab]]]" = queue.Queue()
     spans: List[Tuple[float, float]] = []   # (start, duration) per add
     errors: List[BaseException] = []
-    worker_tid: List[int] = []
 
     def worker():
-        worker_tid.append(threading.get_ident())
         while True:
             item = slabs.get()
             if item is None:
                 return
             it, slab = item
-            t0 = time.perf_counter()
-            try:
-                state.add(it, slab)
-            except BaseException as e:  # surfaced on the main thread
-                errors.append(e)
-                return
-            spans.append((t0, time.perf_counter() - t0))
+            with trace.span("exec.merge_worker") as sp:
+                t0 = time.perf_counter()
+                try:
+                    state.add(it, slab)
+                except BaseException as e:  # surfaced on the main thread
+                    errors.append(e)
+                    return
+                dt = time.perf_counter() - t0
+                sp.measured(t0, dt)
+            spans.append((t0, dt))
 
     th = threading.Thread(target=worker, name="ocean-merge-worker",
                           daemon=True)
     th.start()
     collect_s = 0.0
-    traced = trace.enabled()   # hot loop: no span/attr allocation when off
+    traced = trace.enabled()   # hot loop: no attr allocation when off
     try:
         for it in collect_in_completion_order(items):
-            t0 = time.perf_counter()
-            slab = _materialize(it)
-            dt_c = time.perf_counter() - t0
+            with trace.span("exec.collect") as sp:
+                t0 = time.perf_counter()
+                slab = _materialize(it, copies)
+                dt_c = time.perf_counter() - t0
+                sp.measured(t0, dt_c)
+                if traced:
+                    sp.set(order=it.order, kind=it.tag[0])
             collect_s += dt_c
-            if traced:
-                trace.add_span("exec.collect", t0, dt_c, order=it.order,
-                               kind=it.tag[0])
             slabs.put((it, slab))
     finally:
         collect_end = time.perf_counter()
@@ -638,21 +696,11 @@ def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
         th.join()
     if errors:
         raise errors[0]
-    if traced and worker_tid:
-        # the worker already timed each merge; replay its (t0, duration)
-        # pairs onto its own timeline lane now that it has drained
-        for w0, wdt in spans:
-            trace.add_span("exec.merge_worker", w0, wdt,
-                           tid=worker_tid[0], thread="ocean-merge-worker")
     merge_s = sum(dt for _, dt in spans)
     overlap_s = sum(min(max(collect_end - t0, 0.0), dt) for t0, dt in spans)
-    t0 = time.perf_counter()
-    n_overflow = _run_overflow_fallback(state, plan.products, a, b)
-    t1 = time.perf_counter()
-    c, total = _compact_slabs(state.finalize(), (a.m, b.n), a_values.dtype)
-    t2 = time.perf_counter()
-    trace.add_span("exec.compact", t1, t2 - t1)
-    merge_s += t2 - t0
+    c, total, n_overflow, tail_s = _finish_merge(state, plan, a, b,
+                                                 a_values, stage, copies)
+    merge_s += tail_s
     stage["dispatch"] = dispatch_s
     stage["collect"] = collect_s
     stage["merge"] = merge_s
@@ -673,6 +721,7 @@ def _execute(plan: ExecutionPlan, shards: List[_ShardWork], a: CSR, b: CSR,
              *, stage: Optional[Dict[str, float]], cache_hit: bool,
              mode: str, n_shards: int, shard_imbalance: float,
              post: Optional[MergePostOps] = None,
+             copy_bytes: Optional[Dict[str, int]] = None,
              ) -> Tuple[CSR, OceanReport]:
     if mode not in EXECUTORS:
         raise ValueError(f"unknown executor {mode!r}; expected one of "
@@ -686,47 +735,52 @@ def _execute(plan: ExecutionPlan, shards: List[_ShardWork], a: CSR, b: CSR,
                          f"product has {b.n}")
     stage = dict(stage) if stage else {"analysis": 0.0, "prediction": 0.0,
                                        "binning": 0.0}
-    a_values = np.asarray(a.values)
+    # the planning stages' copies, when this call planned, then ours
+    copies = dict(copy_bytes) if copy_bytes else new_copy_bytes()
 
-    t0 = time.perf_counter()
-    items = _dispatch(shards, a_values, b)
-    dispatch_s = time.perf_counter() - t0
-    trace.add_span("exec.dispatch", t0, dispatch_s, launches=len(items))
+    with trace.span("exec.dispatch") as sp:
+        t0 = time.perf_counter()
+        a_values = to_host(a.values, copies)
+        items = _dispatch(shards, a_values, b, copies)
+        dispatch_s = time.perf_counter() - t0
+        sp.measured(t0, dispatch_s).set(launches=len(items))
 
     collect = _COLLECT_OF[mode]
     c, total, n_overflow, overlap_s, _frac, raw_counts, causes = collect(
-        items, plan, a, b, a_values, stage, dispatch_s, post)
-    # overlap is merge work by definition; clamp so the derived
-    # merge_overlap_frac view stays in [0, 1] even under clock jitter
-    merge_s = stage.get("merge", 0.0)
-    overlap_s = min(max(overlap_s, 0.0), merge_s)
+        items, plan, a, b, a_values, stage, dispatch_s, post, copies)
+    with trace.span("exec.report"):
+        # overlap is merge work by definition; clamp so the derived
+        # merge_overlap_frac view stays in [0, 1] even under clock jitter
+        merge_s = stage.get("merge", 0.0)
+        overlap_s = min(max(overlap_s, 0.0), merge_s)
 
-    # estimation-accuracy telemetry: exact per-row nnz of the raw product
-    # (the merge state's pre-filter counts when fused post-ops pruned the
-    # output, else the output's own indptr diff)
-    exact_nnz = (raw_counts if raw_counts is not None
-                 else np.diff(np.asarray(c.indptr, np.int64)))
-    if plan.feed_forward and causes:
-        # a stale feed-forward size is the likely culprit when the fed
-        # plan's bins overflow; qualify the attribution
-        causes = {f"{k}+stale_feed": v for k, v in causes.items()}
-    accuracy = obs_accuracy.measure_accuracy(plan, exact_nnz, causes)
+        # estimation-accuracy telemetry: exact per-row nnz of the raw
+        # product (the merge state's pre-filter counts when fused post-ops
+        # pruned the output, else the output's own indptr diff)
+        exact_nnz = (raw_counts if raw_counts is not None else
+                     np.diff(to_host(c.indptr, copies).astype(np.int64)))
+        if plan.feed_forward and causes:
+            # a stale feed-forward size is the likely culprit when the fed
+            # plan's bins overflow; qualify the attribution
+            causes = {f"{k}+stale_feed": v for k, v in causes.items()}
+        accuracy = obs_accuracy.measure_accuracy(plan, exact_nnz, causes)
 
-    report = OceanReport(
-        workflow=plan.workflow, er=plan.er, sampled_cr=plan.sampled_cr,
-        nproducts_avg=plan.nproducts_avg,
-        total_products=plan.total_products, m_regs=plan.m_regs,
-        stage_seconds=stage, bins=dict(plan.bins_describe),
-        overflow_rows=n_overflow, nnz_out=total, plan_cache_hit=cache_hit,
-        feed_forward=plan.feed_forward,
-        n_shards=n_shards, shard_imbalance=shard_imbalance,
-        executor=mode, overlap_seconds=overlap_s,
-        analysis_shards=plan.analysis_shards,
-        analysis_shard_seconds=plan.analysis_shard_seconds,
-        raw_row_nnz=raw_counts,
-        wave2_overlap_seconds=plan.wave2_overlap_seconds,
-        wave2_overlapped=plan.wave2_overlapped,
-        estimation_accuracy=accuracy, decision=plan.decision)
+        report = OceanReport(
+            workflow=plan.workflow, er=plan.er, sampled_cr=plan.sampled_cr,
+            nproducts_avg=plan.nproducts_avg,
+            total_products=plan.total_products, m_regs=plan.m_regs,
+            stage_seconds=stage, bins=dict(plan.bins_describe),
+            overflow_rows=n_overflow, nnz_out=total,
+            plan_cache_hit=cache_hit, feed_forward=plan.feed_forward,
+            n_shards=n_shards, shard_imbalance=shard_imbalance,
+            executor=mode, overlap_seconds=overlap_s,
+            analysis_shards=plan.analysis_shards,
+            analysis_shard_seconds=plan.analysis_shard_seconds,
+            raw_row_nnz=raw_counts,
+            wave2_overlap_seconds=plan.wave2_overlap_seconds,
+            wave2_overlapped=plan.wave2_overlapped,
+            estimation_accuracy=accuracy, decision=plan.decision,
+            copy_bytes=copies)
     return c, report
 
 
@@ -735,16 +789,19 @@ def execute_plan(plan: ExecutionPlan, a: CSR, b: CSR, *,
                  cache_hit: bool = False,
                  executor: str = PIPELINED,
                  post: Optional[MergePostOps] = None,
+                 copy_bytes: Optional[Dict[str, int]] = None,
                  ) -> Tuple[CSR, OceanReport]:
     """Run a frozen plan against (possibly new) values of A and B.
 
     ``post`` fuses mask/transform/prune/normalize stages into the merge
     (see :class:`MergePostOps`); the plan itself is post-independent, so
     one cached plan serves masked and unmasked traffic alike.
+    ``stage`` and ``copy_bytes`` carry the seconds and the host<->device
+    bytes of the planning this call did, if any, into the report.
     """
     return _execute(plan, _shards_of_plan(plan), a, b, stage=stage,
                     cache_hit=cache_hit, mode=executor, n_shards=1,
-                    shard_imbalance=1.0, post=post)
+                    shard_imbalance=1.0, post=post, copy_bytes=copy_bytes)
 
 
 def execute_sharded_plan(splan, a: CSR, b: CSR, *,
@@ -752,6 +809,7 @@ def execute_sharded_plan(splan, a: CSR, b: CSR, *,
                          cache_hit: bool = False,
                          executor: str = PIPELINED,
                          post: Optional[MergePostOps] = None,
+                         copy_bytes: Optional[Dict[str, int]] = None,
                          ) -> Tuple[CSR, OceanReport]:
     """Run a :class:`~repro.core.partition.ShardedPlan` across its devices.
 
@@ -771,4 +829,5 @@ def execute_sharded_plan(splan, a: CSR, b: CSR, *,
     return _execute(splan.plan, shards, a, b, stage=stage,
                     cache_hit=cache_hit, mode=executor,
                     n_shards=len(splan.shards),
-                    shard_imbalance=splan.imbalance, post=post)
+                    shard_imbalance=splan.imbalance, post=post,
+                    copy_bytes=copy_bytes)

@@ -45,7 +45,13 @@ from . import tuning as tuning_mod
 from .analysis import (SHARD_ROW_FLOOR, AnalysisResult, OceanConfig, analyze,
                        sharded_merge_estimate, sketches_for)
 from .binning import BinPlan, plan_bins
+from .dispatch import new_copy_bytes, to_device, to_host
 from .formats import CSR, csr_from_arrays, flat_gather_index, pow2_at_least
+
+
+# stage_seconds keys that time part of another stage: the overflow
+# fallback runs inside the merge ("overflow" on the serial executor)
+NESTED_STAGES = ("fallback",)
 
 
 @dataclasses.dataclass
@@ -97,10 +103,17 @@ class OceanReport:
     # workflow chosen plus every input to the choice (Table 1 thresholds,
     # ER, sampled CR, forcing) — a build-time fact replayed on cache hits
     decision: Optional[Dict] = None
+    # bytes this call moved between host and device, by direction
+    # ("d2h", "h2d"): the executor's copies both ways, and the planning
+    # stages' read-backs and uploads when this call planned
+    # (core.dispatch.to_host / to_device count them)
+    copy_bytes: Dict[str, int] = dataclasses.field(
+        default_factory=new_copy_bytes)
 
     @property
     def total_seconds(self) -> float:
-        return sum(self.stage_seconds.values())
+        return sum(v for k, v in self.stage_seconds.items()
+                   if k not in NESTED_STAGES)
 
     @property
     def setup_seconds(self) -> float:
@@ -269,6 +282,10 @@ class ExecutionPlan:
     b_sketches: Optional[jax.Array]
     hash: List[HashBinExec] = dataclasses.field(default_factory=list)
     build_seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # bytes the build moved between host and device (OceanReport.copy_bytes
+    # of the call that built the plan counts them)
+    build_copy_bytes: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
     # how the analysis stage ran when this plan was built (surfaced into
     # OceanReport on every execution of the plan)
     analysis_shards: int = 1
@@ -400,163 +417,182 @@ def build_plan(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
                     num_rows_a=a.m, n_cols_b=b.n), np.float64)
 
     # ---------------- analysis ----------------
-    t0 = time.perf_counter()
-    ov_s, ov_pending = 0.0, False
-    if analysis is None:
-        analysis = analyze(a, b, cfg, sketch_cache=sketch_cache,
-                           devices=analysis_devices,
-                           known_sizes=known_sizes,
-                           overlap_work=_wave2_prework)
-        ov_s = analysis.wave2_overlap_seconds
-        ov_pending = analysis.wave2_overlapped
-    if known_sizes is None and analysis.known_sizes is not None:
-        known_sizes = analysis.known_sizes
-    # exact feed-forward sizes trump both Table-1 selection and ablation
-    # forcing: there is nothing left to estimate
-    wf = ("known" if known_sizes is not None
-          else (force_workflow or analysis.workflow))
-    products = np.asarray(analysis.products_row, np.int64)
-    total_products = analysis.total_products
-    out_lo = np.asarray(analysis.out_lo)
-    out_hi = np.asarray(analysis.out_hi)
-    a_row_nnz = prework.get("a_row_nnz")
-    if a_row_nnz is None:
-        ptr = np.asarray(a.indptr, np.int64)
-        a_row_nnz = ptr[1:] - ptr[:-1]
-    stage["analysis"] = time.perf_counter() - t0
-    trace.add_span("plan.analysis", t0, stage["analysis"], workflow=wf)
+    copies = new_copy_bytes()
+    with trace.span("plan.analysis") as sp:
+        t0 = time.perf_counter()
+        ov_s, ov_pending = 0.0, False
+        if analysis is None:
+            analysis = analyze(a, b, cfg, sketch_cache=sketch_cache,
+                               devices=analysis_devices,
+                               known_sizes=known_sizes,
+                               overlap_work=_wave2_prework)
+            ov_s = analysis.wave2_overlap_seconds
+            ov_pending = analysis.wave2_overlapped
+            copies = dict(analysis.copy_bytes)
+        if known_sizes is None and analysis.known_sizes is not None:
+            known_sizes = analysis.known_sizes
+        # exact feed-forward sizes trump both Table-1 selection and
+        # ablation forcing: there is nothing left to estimate
+        wf = ("known" if known_sizes is not None
+              else (force_workflow or analysis.workflow))
+        products = np.asarray(analysis.products_row, np.int64)
+        total_products = analysis.total_products
+        out_lo = np.asarray(analysis.out_lo)
+        out_hi = np.asarray(analysis.out_hi)
+        a_row_nnz = prework.get("a_row_nnz")
+        if a_row_nnz is None:
+            ptr = np.asarray(a.indptr, np.int64)
+            a_row_nnz = ptr[1:] - ptr[:-1]
+        stage["analysis"] = time.perf_counter() - t0
+        sp.measured(t0, stage["analysis"]).set(workflow=wf)
 
     # ---------------- size prediction ----------------
-    t0 = time.perf_counter()
-    sketches = analysis.b_sketches
-    if wf == "known":
-        # feed-forward: the exact sizes are the prediction, at zero cost.
-        # A stale/elided feed can report 0 for a row that is provably
-        # non-empty (products > 0 implies structural nnz >= 1); clamp to 1
-        # so capacity ladders never size a live row's table from 0 and the
-        # overflow fallback stays a correction, not a crutch.
-        pred = np.asarray(known_sizes, np.float64)
-        pred = np.where(products > 0, np.maximum(pred, 1.0), 0.0)
-        pred = np.minimum(pred, products)
-    elif wf == "estimation":
-        if sketches is None:
-            sketches = sketches_for(b, analysis.m_regs, cfg.seed,
-                                    sketch_cache)
-        # Sentinel concat padded to the pow2 row bucket: rows past b.m are
-        # all-zero (the HLL identity / Pallas pad sentinel), so values are
-        # untouched while the merge-stage jit specialization stays shared
-        # across matrices in the same bucket.
-        rb_pad = pow2_at_least(max(b.m, 1), floor=SHARD_ROW_FLOOR)
-        sk = jnp.concatenate(
-            [sketches, jnp.zeros((rb_pad + 1 - sketches.shape[0],
-                                  sketches.shape[1]), jnp.int32)], axis=0)
-        est = sharded_merge_estimate(a, sk, clip_max=b.n,
-                                     devices=analysis_devices)
-        pred = np.maximum(np.asarray(est, np.float64), 1.0)
-        pred = np.where(products > 0, pred, 0.0)
-        pred = np.minimum(pred, products)  # distinct count <= products
-    elif wf == "symbolic":
-        pred = prework.get("symbolic_pred")
-        if pred is None and jax.default_backend() == "cpu":
-            # Device dispatch plus the pow2-padded device sort dominate
-            # fresh-plan latency on CPU; the host twin sorts the exact
-            # product count and is bit-identical (see symbolic_exact_host).
-            pred = np.asarray(esc_mod.symbolic_exact_host(
-                a.indptr, a.indices, b.indptr, b.indices,
-                num_rows_a=a.m, n_cols_b=b.n), np.float64)
-        elif pred is None:
-            p_cap = pow2_at_least(total_products, floor=64)
-            pred = np.asarray(
-                esc_mod.symbolic_exact(a.indptr, a.indices, b.indptr,
-                                       b.indices, p_cap=p_cap,
-                                       num_rows_a=a.m),
-                np.float64)
-    else:  # upper_bound
-        pred = products.astype(np.float64)
-    stage["prediction"] = time.perf_counter() - t0
-    trace.add_span("plan.prediction", t0, stage["prediction"])
+    with trace.span("plan.prediction") as sp:
+        t0 = time.perf_counter()
+        sketches = analysis.b_sketches
+        if wf == "known":
+            # feed-forward: the exact sizes are the prediction, at zero
+            # cost. A stale/elided feed can report 0 for a row that is
+            # provably non-empty (products > 0 implies structural nnz >= 1);
+            # clamp to 1 so capacity ladders never size a live row's table
+            # from 0 and the overflow fallback stays a correction, not a
+            # crutch.
+            pred = np.asarray(known_sizes, np.float64)
+            pred = np.where(products > 0, np.maximum(pred, 1.0), 0.0)
+            pred = np.minimum(pred, products)
+        elif wf == "estimation":
+            if sketches is None:
+                sketches = sketches_for(b, analysis.m_regs, cfg.seed,
+                                        sketch_cache)
+            # Sentinel concat padded to the pow2 row bucket: rows past b.m
+            # are all-zero (the HLL identity / Pallas pad sentinel), so
+            # values are untouched while the merge-stage jit specialization
+            # stays shared across matrices in the same bucket.
+            rb_pad = pow2_at_least(max(b.m, 1), floor=SHARD_ROW_FLOOR)
+            sk = jnp.concatenate(
+                [sketches, jnp.zeros((rb_pad + 1 - sketches.shape[0],
+                                      sketches.shape[1]), jnp.int32)],
+                axis=0)
+            est = sharded_merge_estimate(a, sk, clip_max=b.n,
+                                         devices=analysis_devices,
+                                         copies=copies)
+            pred = np.maximum(np.asarray(est, np.float64), 1.0)
+            pred = np.where(products > 0, pred, 0.0)
+            pred = np.minimum(pred, products)  # distinct count <= products
+        elif wf == "symbolic":
+            pred = prework.get("symbolic_pred")
+            if pred is None and jax.default_backend() == "cpu":
+                # Device dispatch plus the pow2-padded device sort dominate
+                # fresh-plan latency on CPU; the host twin sorts the exact
+                # product count and is bit-identical (see
+                # symbolic_exact_host).
+                pred = np.asarray(esc_mod.symbolic_exact_host(
+                    a.indptr, a.indices, b.indptr, b.indices,
+                    num_rows_a=a.m, n_cols_b=b.n), np.float64)
+            elif pred is None:
+                p_cap = pow2_at_least(total_products, floor=64)
+                pred = to_host(
+                    esc_mod.symbolic_exact(a.indptr, a.indices, b.indptr,
+                                           b.indices, p_cap=p_cap,
+                                           num_rows_a=a.m),
+                    copies).astype(np.float64)
+        else:  # upper_bound
+            pred = products.astype(np.float64)
+        stage["prediction"] = time.perf_counter() - t0
+        sp.measured(t0, stage["prediction"])
 
     # ---------------- binning ----------------
-    t0 = time.perf_counter()
-    assisted_cr = analysis.conservative_cr if (assisted and wf == "upper_bound"
-                                               and analysis.cr_mean) else None
-    # the hash rung rides the hybrid-accumulator switch (V1/V2 ablations
-    # disable it with ESC) plus its own config knob; the measured load
-    # factor steers how binning sizes primary tables
-    hash_enabled = hybrid and cfg.hash_rung
-    ref_tuned = (tuning_mod.hash_tuning_for(tuning_mod.REFERENCE_RUNG)
-                 if hash_enabled else tuning_mod.DEFAULT_TUNING)
-    plan = plan_bins(pred, products, out_lo, out_hi, a_row_nnz, b.n,
-                     expansion=cfg.expansion_for(analysis.m_regs),
-                     workflow=wf, esc_enabled=hybrid,
-                     assisted_cr=assisted_cr, hash_enabled=hash_enabled,
-                     load_factor=ref_tuned.load_factor,
-                     tile_rows=ref_tuned.tile_rows)
-    if not hybrid:
-        # V1/V2: long rows fall back to the global ESC pass instead of the
-        # column-tiled kernel (the paper's 'nonadaptive global kernel').
-        longrow_rows = np.concatenate(
-            [bn.rows for bn in plan.dense_bins if bn.is_longrow]
-            or [np.zeros(0, np.int64)])
-        plan = BinPlan(
-            dense_bins=[bn for bn in plan.dense_bins if not bn.is_longrow],
-            esc_rows=np.concatenate([plan.esc_rows, longrow_rows]),
-            esc_caps=np.concatenate(
-                [plan.esc_caps, products[longrow_rows]]),
-            empty_rows=plan.empty_rows, hash_bins=plan.hash_bins)
+    with trace.span("plan.binning") as sp:
+        t0 = time.perf_counter()
+        assisted_cr = (analysis.conservative_cr
+                       if (assisted and wf == "upper_bound"
+                           and analysis.cr_mean) else None)
+        # the hash rung rides the hybrid-accumulator switch (V1/V2
+        # ablations disable it with ESC) plus its own config knob; the
+        # measured load factor steers how binning sizes primary tables
+        hash_enabled = hybrid and cfg.hash_rung
+        ref_tuned = (tuning_mod.hash_tuning_for(tuning_mod.REFERENCE_RUNG)
+                     if hash_enabled else tuning_mod.DEFAULT_TUNING)
+        plan = plan_bins(pred, products, out_lo, out_hi, a_row_nnz, b.n,
+                         expansion=cfg.expansion_for(analysis.m_regs),
+                         workflow=wf, esc_enabled=hybrid,
+                         assisted_cr=assisted_cr,
+                         hash_enabled=hash_enabled,
+                         load_factor=ref_tuned.load_factor,
+                         tile_rows=ref_tuned.tile_rows)
+        if not hybrid:
+            # V1/V2: long rows fall back to the global ESC pass instead of
+            # the column-tiled kernel (the paper's 'nonadaptive global
+            # kernel').
+            longrow_rows = np.concatenate(
+                [bn.rows for bn in plan.dense_bins if bn.is_longrow]
+                or [np.zeros(0, np.int64)])
+            plan = BinPlan(
+                dense_bins=[bn for bn in plan.dense_bins
+                            if not bn.is_longrow],
+                esc_rows=np.concatenate([plan.esc_rows, longrow_rows]),
+                esc_caps=np.concatenate(
+                    [plan.esc_caps, products[longrow_rows]]),
+                empty_rows=plan.empty_rows, hash_bins=plan.hash_bins)
 
-    # Freeze per-bin structure: gather maps + value-independent ELL blocks.
-    dense_execs: List[DenseBinExec] = []
-    for bin_id, bn in enumerate(plan.dense_bins):
-        pos, valid, a_rows, a_starts, a_lens = kops.prep_bin_structure(
-            a, b, bn.rows, bn.ell_width)
-        lo_arr = (out_lo[bn.rows] if not bn.is_longrow
-                  else np.zeros(len(bn.rows)))
-        row_lo = jnp.asarray(lo_arr.reshape(-1, 1).astype(np.int32))
-        bin_products = int(np.asarray(a_lens, np.int64).sum())
-        dense_execs.append(DenseBinExec(
-            window=bn.window, col_tiles=bn.col_tiles, cap=bn.cap,
-            rows=bn.rows, ell_width=bn.ell_width, is_longrow=bn.is_longrow,
-            pos=pos, valid=valid, a_rows=jnp.asarray(a_rows),
-            a_starts=jnp.asarray(a_starts), a_lens=jnp.asarray(a_lens),
-            row_lo=row_lo, cost=np.asarray(bn.cost, np.int64),
-            bin_id=bin_id, n_valid=len(bn.rows),
-            p_cap=pow2_at_least(bin_products, floor=64)))
+        # Freeze per-bin structure: gather maps + value-independent ELL
+        # blocks, the latter committed to the device.
+        dense_execs: List[DenseBinExec] = []
+        for bin_id, bn in enumerate(plan.dense_bins):
+            pos, valid, a_rows, a_starts, a_lens = kops.prep_bin_structure(
+                a, b, bn.rows, bn.ell_width)
+            lo_arr = (out_lo[bn.rows] if not bn.is_longrow
+                      else np.zeros(len(bn.rows)))
+            row_lo = to_device(lo_arr.reshape(-1, 1).astype(np.int32),
+                               copies)
+            bin_products = int(np.asarray(a_lens, np.int64).sum())
+            dense_execs.append(DenseBinExec(
+                window=bn.window, col_tiles=bn.col_tiles, cap=bn.cap,
+                rows=bn.rows, ell_width=bn.ell_width,
+                is_longrow=bn.is_longrow, pos=pos, valid=valid,
+                a_rows=to_device(a_rows, copies),
+                a_starts=to_device(a_starts, copies),
+                a_lens=to_device(a_lens, copies),
+                row_lo=row_lo, cost=np.asarray(bn.cost, np.int64),
+                bin_id=bin_id, n_valid=len(bn.rows),
+                p_cap=pow2_at_least(bin_products, floor=64)))
 
-    hash_execs: List[HashBinExec] = []
-    for hash_id, hb in enumerate(plan.hash_bins):
-        pos, valid, a_rows, a_starts, a_lens = kops.prep_bin_structure(
-            a, b, hb.rows, hb.ell_width)
-        bin_products = int(np.asarray(a_lens, np.int64).sum())
-        tuned = tuning_mod.hash_tuning_for(hb.table)
-        hash_execs.append(HashBinExec(
-            table=hb.table, spill=hb.spill, rows=hb.rows,
-            ell_width=hb.ell_width, pos=pos, valid=valid,
-            a_rows=jnp.asarray(a_rows), a_starts=jnp.asarray(a_starts),
-            a_lens=jnp.asarray(a_lens),
-            cost=np.asarray(hb.cost, np.int64),
-            bin_id=len(dense_execs) + hash_id, n_valid=len(hb.rows),
-            p_cap=pow2_at_least(bin_products, floor=64),
-            f_chunk=tuned.f_chunk, tile=tuned.tile_rows))
+        hash_execs: List[HashBinExec] = []
+        for hash_id, hb in enumerate(plan.hash_bins):
+            pos, valid, a_rows, a_starts, a_lens = kops.prep_bin_structure(
+                a, b, hb.rows, hb.ell_width)
+            bin_products = int(np.asarray(a_lens, np.int64).sum())
+            tuned = tuning_mod.hash_tuning_for(hb.table)
+            hash_execs.append(HashBinExec(
+                table=hb.table, spill=hb.spill, rows=hb.rows,
+                ell_width=hb.ell_width, pos=pos, valid=valid,
+                a_rows=to_device(a_rows, copies),
+                a_starts=to_device(a_starts, copies),
+                a_lens=to_device(a_lens, copies),
+                cost=np.asarray(hb.cost, np.int64),
+                bin_id=len(dense_execs) + hash_id, n_valid=len(hb.rows),
+                p_cap=pow2_at_least(bin_products, floor=64),
+                f_chunk=tuned.f_chunk, tile=tuned.tile_rows))
 
-    esc_exec = None
-    if len(plan.esc_rows):
-        rows = plan.esc_rows
-        if (prework.get("esc_rows") is not None
-                and np.array_equal(prework["esc_rows"], rows)):
-            # the wave-2-overlapped prework computed this exact row set
-            sub_ptr, src = prework["sub_ptr"], prework["src"]
-            p_cap = prework["p_cap"]
-        else:
-            sub_ptr, src = flat_gather_index(a.indptr, rows)
-            p_cap = pow2_at_least(int(products[rows].sum()), floor=64)
-        esc_exec = EscExec(rows=rows, sub_indptr=sub_ptr.astype(np.int32),
-                           sub_indices=np.asarray(a.indices)[src], src=src,
-                           p_cap=p_cap, out_cap=p_cap,
-                           cost=np.asarray(plan.esc_costs, np.int64),
-                           n_valid=len(rows))
-    stage["binning"] = time.perf_counter() - t0
-    trace.add_span("plan.binning", t0, stage["binning"])
+        esc_exec = None
+        if len(plan.esc_rows):
+            rows = plan.esc_rows
+            if (prework.get("esc_rows") is not None
+                    and np.array_equal(prework["esc_rows"], rows)):
+                # the wave-2-overlapped prework computed this row set
+                sub_ptr, src = prework["sub_ptr"], prework["src"]
+                p_cap = prework["p_cap"]
+            else:
+                sub_ptr, src = flat_gather_index(a.indptr, rows)
+                p_cap = pow2_at_least(int(products[rows].sum()), floor=64)
+            esc_exec = EscExec(
+                rows=rows, sub_indptr=sub_ptr.astype(np.int32),
+                sub_indices=np.asarray(a.indices)[src], src=src,
+                p_cap=p_cap, out_cap=p_cap,
+                cost=np.asarray(plan.esc_costs, np.int64),
+                n_valid=len(rows))
+        stage["binning"] = time.perf_counter() - t0
+        sp.measured(t0, stage["binning"])
 
     decision = obs_accuracy.record_decision(
         workflow=wf, forced=force_workflow, feed_forward=(wf == "known"),
@@ -572,7 +608,8 @@ def build_plan(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
         nproducts_avg=analysis.nproducts_avg, total_products=total_products,
         m_regs=analysis.m_regs, b_sketches=sketches
         if wf == "estimation" else analysis.b_sketches,
-        build_seconds=stage, analysis_shards=analysis.n_shards,
+        build_seconds=stage, build_copy_bytes=copies,
+        analysis_shards=analysis.n_shards,
         analysis_shard_seconds=analysis.shard_seconds,
         feed_forward=(wf == "known"),
         wave2_overlap_seconds=ov_s, wave2_overlapped=ov_pending,
@@ -592,26 +629,29 @@ def execute_plan(plan: ExecutionPlan, a: CSR, b: CSR, *,
                  stage: Optional[Dict[str, float]] = None,
                  cache_hit: bool = False,
                  executor: str = "pipelined",
-                 post=None) -> Tuple[CSR, OceanReport]:
+                 post=None, copy_bytes: Optional[Dict[str, int]] = None,
+                 ) -> Tuple[CSR, OceanReport]:
     """Run a frozen plan against (possibly new) values of A and B.
 
     ``post`` (a :class:`~repro.core.executor.MergePostOps`) fuses
     mask/transform/prune/normalize stages into the executor's merge."""
     from .executor import execute_plan as _execute
     return _execute(plan, a, b, stage=stage, cache_hit=cache_hit,
-                    executor=executor, post=post)
+                    executor=executor, post=post, copy_bytes=copy_bytes)
 
 
 def execute_sharded_plan(splan, a: CSR, b: CSR, *,
                          stage: Optional[Dict[str, float]] = None,
                          cache_hit: bool = False,
                          executor: str = "pipelined",
-                         post=None) -> Tuple[CSR, OceanReport]:
+                         post=None,
+                         copy_bytes: Optional[Dict[str, int]] = None,
+                         ) -> Tuple[CSR, OceanReport]:
     """Run a :class:`~repro.core.partition.ShardedPlan` across its devices
     through the unified executor pipeline."""
     from .executor import execute_sharded_plan as _execute
     return _execute(splan, a, b, stage=stage, cache_hit=cache_hit,
-                    executor=executor, post=post)
+                    executor=executor, post=post, copy_bytes=copy_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -123,11 +123,8 @@ def ocean_spgemm(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
             # convenience path: partitions on every call. For repeated
             # values-only updates partition once (partition_plan) and pass
             # the ShardedPlan; the cost is surfaced as the partition stage.
-            t0 = time.perf_counter()
-            splan = partition_plan(plan, devices)
-            stage = {"analysis": 0.0, "prediction": 0.0, "binning": 0.0,
-                     "partition": time.perf_counter() - t0}
-            trace.add_span("plan.partition", t0, stage["partition"])
+            stage = {"analysis": 0.0, "prediction": 0.0, "binning": 0.0}
+            splan = _partition(plan, devices, stage)
             return execute_sharded_plan(splan, a, b, stage=stage,
                                         executor=executor, post=post)
         return execute_plan(plan, a, b, executor=executor, post=post)
@@ -137,14 +134,14 @@ def ocean_spgemm(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
                if analysis_devices is not None else devs)
     cache_obj = _resolve_cache(cache) if analysis is None else None
     if cache_obj is not None:
-        t0 = time.perf_counter()
-        key = structure_key(a, b, cfg, force_workflow, assisted, hybrid,
-                            known_sizes=known_sizes)
-        lkey = key if devs is None else key + "|" + topology_key(devs)
-        cached = cache_obj.lookup(lkey)
-        lookup_s = time.perf_counter() - t0
-        trace.add_span("plan.lookup", t0, lookup_s,
-                       hit=bool(cached is not None))
+        with trace.span("plan.lookup") as sp:
+            t0 = time.perf_counter()
+            key = structure_key(a, b, cfg, force_workflow, assisted, hybrid,
+                                known_sizes=known_sizes)
+            lkey = key if devs is None else key + "|" + topology_key(devs)
+            cached = cache_obj.lookup(lkey)
+            lookup_s = time.perf_counter() - t0
+            sp.measured(t0, lookup_s).set(hit=bool(cached is not None))
         if cached is not None:
             # the cached path's entire host-side setup cost is the O(nnz)
             # structure hash + LRU lookup
@@ -160,6 +157,7 @@ def ocean_spgemm(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
         # sharded miss: reuse a cached base plan for this structure if one
         # exists (peek — the request-level stats already counted the miss)
         base = cache_obj.peek(key) if devs is not None else None
+        copies = None
         if base is not None:
             stage = {"analysis": 0.0, "prediction": 0.0, "binning": 0.0}
         else:
@@ -170,31 +168,41 @@ def ocean_spgemm(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,
                               known_sizes=known_sizes)
             cache_obj.insert(key, base)
             stage = dict(base.build_seconds)
+            copies = base.build_copy_bytes
         stage["plan_lookup"] = lookup_s
         if devs is None:
             return execute_plan(base, a, b, stage=stage, executor=executor,
-                                post=post)
-        t0 = time.perf_counter()
-        splan = partition_plan(base, devs)
-        stage["partition"] = time.perf_counter() - t0
-        trace.add_span("plan.partition", t0, stage["partition"])
+                                post=post, copy_bytes=copies)
+        splan = _partition(base, devs, stage)
         cache_obj.insert(lkey, splan)
         return execute_sharded_plan(splan, a, b, stage=stage,
-                                    executor=executor, post=post)
+                                    executor=executor, post=post,
+                                    copy_bytes=copies)
     fresh = build_plan(a, b, cfg, force_workflow=force_workflow,
                        assisted=assisted, hybrid=hybrid,
                        analysis=analysis, sketch_cache=sketch_cache,
                        analysis_devices=an_devs, known_sizes=known_sizes)
     if devs is not None:
         stage = dict(fresh.build_seconds)
-        t0 = time.perf_counter()
-        splan = partition_plan(fresh, devs)
-        stage["partition"] = time.perf_counter() - t0
-        trace.add_span("plan.partition", t0, stage["partition"])
+        splan = _partition(fresh, devs, stage)
         return execute_sharded_plan(splan, a, b, stage=stage,
-                                    executor=executor, post=post)
+                                    executor=executor, post=post,
+                                    copy_bytes=fresh.build_copy_bytes)
     return execute_plan(fresh, a, b, stage=fresh.build_seconds,
-                        executor=executor, post=post)
+                        executor=executor, post=post,
+                        copy_bytes=fresh.build_copy_bytes)
+
+
+def _partition(plan: ExecutionPlan, devices, stage: Dict[str, float]
+               ) -> ShardedPlan:
+    """``partition_plan`` under a ``plan.partition`` span, its seconds in
+    ``stage["partition"]``."""
+    with trace.span("plan.partition") as sp:
+        t0 = time.perf_counter()
+        splan = partition_plan(plan, devices)
+        stage["partition"] = time.perf_counter() - t0
+        sp.measured(t0, stage["partition"])
+    return splan
 
 
 def warm_plan(a: CSR, b: CSR, cfg: OceanConfig = OceanConfig(), *,

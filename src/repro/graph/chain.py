@@ -183,6 +183,7 @@ class ChainRunner:
         # re-partitioning), "known" (fresh build from a size feed),
         # "estimated" (fresh build with full prediction)
         resolved = "hit"
+        copies = None   # the plan build's copies, when this call built one
         if plan is None:
             base = (self.plan_cache.peek(key) if self.devices is not None
                     else None)
@@ -194,6 +195,7 @@ class ChainRunner:
                                   known_sizes=known)
                 self.plan_cache.insert(key, base)
                 stage = dict(base.build_seconds)
+                copies = base.build_copy_bytes
                 resolved = "known" if known is not None else "estimated"
             else:
                 stage = {"analysis": 0.0, "prediction": 0.0, "binning": 0.0}
@@ -213,11 +215,11 @@ class ChainRunner:
             c_out, rep = execute_sharded_plan(plan, c, rhs, stage=stage,
                                               cache_hit=hit,
                                               executor=self.executor,
-                                              post=post)
+                                              post=post, copy_bytes=copies)
         else:
             c_out, rep = execute_plan(plan, c, rhs, stage=stage,
                                       cache_hit=hit, executor=self.executor,
-                                      post=post)
+                                      post=post, copy_bytes=copies)
 
         # record the measured exact raw product sizes for this pattern
         # pair — the feed the next plan of the same pair is built from.

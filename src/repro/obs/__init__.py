@@ -1,7 +1,8 @@
 """Ocean observability: span tracing, metrics registry, estimation-
-accuracy telemetry. Zero external dependencies; tracing and the global
-registry are off by default and the instrumented paths are allocation-
-free when off. See ``docs/observability.md``.
+accuracy telemetry. Tracing and the global registry are off by default
+and the instrumented paths are allocation-free when off; live spans
+reach ``jax.profiler`` traces as annotations. See
+``docs/observability.md``.
 """
 from .accuracy import (EstimationAccuracy, measure_accuracy,  # noqa: F401
                        record_decision)

@@ -1,36 +1,43 @@
-"""Span tracing for the Ocean pipeline (zero-dependency, thread-safe).
+"""Span tracing for the Ocean pipeline (thread-safe).
 
 A :class:`Tracer` records nested, named spans — ``with span("analysis.wave1",
 shard=i): ...`` — across every thread that touches a request: the workflow
 entry point, the planner's analysis/prediction/binning stages, the
 executor's dispatch/collect/merge pipeline (including the dedicated merge
 worker thread), and the serving pool's queue-wait/batch/warmer paths.
-Recorded spans export as Chrome/Perfetto ``trace_event`` JSON through
-``tools/trace_export.py``.
+
+While a tracer is installed, every live span also opens a
+``jax.profiler.TraceAnnotation`` of its own name around its block. Under
+``jax.profiler.start_trace`` the span then lands on the profiler's host
+plane, on the same clock as the device's operations, on the line of the
+thread that ran it; ``docs/observability.md`` says how to take such a
+trace. Retroactive spans (:func:`add_span`) stay in the tracer alone: the
+serving pool's synthetic queue-wait lanes use them.
 
 Tracing is *off by default* and the instrumented paths are allocation-free
 when it is off:
 
-* :func:`span` returns the singleton :data:`NULL_SPAN` (no ``Span`` object
-  is ever constructed — ``tests/test_obs.py`` pins this with a call-count
-  shim on ``Span.__init__``);
-* :func:`add_span` (retroactive recording for code that already measured a
-  ``(t0, duration)`` pair, e.g. the pool's queue-wait accounting) returns
-  after one module-global read;
+* :func:`span` returns the singleton :data:`NULL_SPAN` (no ``Span`` and no
+  ``TraceAnnotation`` is ever constructed — ``tests/test_obs.py`` pins this
+  with call-count shims on both);
+* :func:`add_span` returns after one module-global read;
 * hot per-slab loops guard on :func:`enabled` before building any
   attribute dict.
 
 Timing discipline: instrumented stages measure **once** with
 ``time.perf_counter()`` and feed the same measurement to both the stage
-dict on :class:`~repro.core.planner.OceanReport` and the span record — the
-report's timing fields are views of the numbers the spans carry, so the
-two can never drift (see ``docs/observability.md``).
+dict on :class:`~repro.core.planner.OceanReport` and the span record
+(:meth:`Span.measured`) — the report's timing fields are views of the
+numbers the spans carry, so the two can never drift (see
+``docs/observability.md``).
 """
 from __future__ import annotations
 
 import threading
 import time
 from typing import Dict, List, Optional
+
+import jax
 
 __all__ = ["Tracer", "Span", "NULL_SPAN", "span", "add_span", "enabled",
            "install", "current", "tracing"]
@@ -44,7 +51,7 @@ class Tracer:
     with per-thread nesting stacks, so concurrent threads trace
     independently and a span's parent is whatever span was open on the
     *same thread* when it closed. ``t0`` is absolute ``perf_counter``
-    time; exporters rebase on :attr:`epoch` (captured at construction).
+    time; :attr:`epoch` is the tracer's construction time.
     """
 
     def __init__(self):
@@ -73,11 +80,11 @@ class Tracer:
         """Record a span retroactively from an already-measured
         ``(t0, duration)`` pair (``perf_counter`` seconds). The span joins
         the calling thread's timeline unless ``tid``/``thread`` override
-        it (e.g. the threaded executor recording its merge worker's spans
-        after joining it); it nests under the currently open span, if
-        any — unless ``tid`` points at another thread, in which case it is
-        recorded parentless (the other thread's nesting is unknown
-        here)."""
+        it (e.g. the serving pool's one synthetic lane per request); it
+        nests under the currently open span, if any — unless ``tid``
+        points at another thread, in which case it is recorded parentless
+        (the other thread's nesting is unknown here). It reaches no
+        profiler trace: only a live span's block can be annotated."""
         stack = self._stack() if tid is None else ()
         self._record(name, t0, max(dur, 0.0),
                      tid if tid is not None else threading.get_ident(),
@@ -112,34 +119,48 @@ class Tracer:
 
 
 class Span:
-    """One open span; records itself on ``__exit__``."""
+    """One open span; records itself on ``__exit__``, and brackets its
+    block with a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "attrs", "t0")
+    __slots__ = ("_tracer", "name", "attrs", "t0", "dur", "_annotation")
 
     def __init__(self, tracer: Tracer, name: str, attrs: Dict):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
+        self.dur: Optional[float] = None
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes after opening (e.g. results known at exit)."""
         self.attrs.update(attrs)
         return self
 
+    def measured(self, t0: float, dur: float) -> "Span":
+        """Record the caller's own ``perf_counter`` measurement of the
+        block — the one that also feeds ``stage_seconds`` — in place of
+        the span's."""
+        self.t0, self.dur = t0, dur
+        return self
+
     def __enter__(self) -> "Span":
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._tracer._stack().append(self.name)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        dur = time.perf_counter() - self.t0
+        dur = (time.perf_counter() - self.t0 if self.dur is None
+               else self.dur)
         stack = self._tracer._stack()
         stack.pop()
         self._tracer._record(
-            self.name, self.t0, dur, threading.get_ident(),
+            self.name, self.t0, max(dur, 0.0), threading.get_ident(),
             threading.current_thread().name,
             stack[-1] if stack else None, self.attrs)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -152,6 +173,9 @@ class _NullSpan:
     __slots__ = ()
 
     def set(self, **attrs) -> "_NullSpan":
+        return self
+
+    def measured(self, t0: float, dur: float) -> "_NullSpan":
         return self
 
     def __enter__(self) -> "_NullSpan":

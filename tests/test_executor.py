@@ -21,7 +21,8 @@ except ImportError:  # deterministic fixed-seed fallback, same properties
     from _hypothesis_fallback import given, settings, st
 
 from conftest import assert_bit_identical
-from repro.core import esc, executor, formats, partition, planner, workflow
+from repro.core import (dispatch, esc, executor, formats, partition, planner,
+                        workflow)
 from repro.core.analysis import OceanConfig
 from repro.kernels import ops as kops
 from repro.kernels import spgemm_dense as kdense
@@ -184,9 +185,9 @@ def test_threaded_equals_serial_under_slow_collect(monkeypatch):
 
     real = executor._materialize
 
-    def slow_materialize(it):
+    def slow_materialize(it, copies):
         time.sleep(0.005)  # sleep releases the GIL: worker merges meanwhile
-        return real(it)
+        return real(it, copies)
 
     monkeypatch.setattr(executor, "_materialize", slow_materialize)
     c_thr, rep = planner.execute_plan(plan, a, a, executor="threaded")
@@ -427,7 +428,8 @@ def test_esc_overflow_error_unified():
     fake = types.SimpleNamespace(nnz=np.int32(10), indptr=None,
                                  indices=None, values=None)
     with pytest.raises(esc.EscOverflowError):
-        executor._esc_to_slab(fake, np.arange(3), 3, out_cap=4)
+        executor._esc_to_slab(fake, np.arange(3), 3, out_cap=4,
+                              copies=dispatch.new_copy_bytes())
 
 
 def test_plan_cache_thread_safety_smoke():

@@ -1,19 +1,20 @@
-"""Observability layer: tracer fast path, Perfetto export round-trip,
-estimation-accuracy telemetry, the metrics registry, and ServiceStats
-aggregation."""
+"""Observability layer: tracer fast path, spans on the profiler's host
+plane, host<->device copy counts, estimation-accuracy telemetry, the
+metrics registry, and ServiceStats aggregation."""
+import glob
 import json
 import threading
 from types import SimpleNamespace
 
+import jax
 import numpy as np
 import pytest
 
-from repro.core import formats
-from repro.core.planner import OceanReport
+from repro.core import dispatch, formats
+from repro.core.planner import OceanReport, PlanCache
 from repro.core.workflow import ocean_spgemm
 from repro.obs import accuracy, metrics, trace
 from repro.serving.spgemm_service import ServiceStats
-from tools.trace_export import validate_chrome_trace, write_chrome_trace
 
 
 # ---------------------------------------------------------------------------
@@ -68,10 +69,30 @@ def test_tracing_restores_previous_tracer():
     assert trace.current() is None and not trace.enabled()
 
 
+def _count_annotations(monkeypatch, calls):
+    """Replace ``jax.profiler.TraceAnnotation`` with a counting twin."""
+    real = jax.profiler.TraceAnnotation
+
+    class Counting:
+        def __init__(self, name, **kw):
+            calls["ann"] += 1
+            self._ann = real(name, **kw)
+
+        def __enter__(self):
+            self._ann.__enter__()
+            return self
+
+        def __exit__(self, *exc):
+            return self._ann.__exit__(*exc)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+
+
 def test_disabled_path_constructs_no_span(monkeypatch):
     """The no-op fast path: with tracing off, span() must return the
-    NULL_SPAN singleton without ever constructing a Span."""
-    calls = {"n": 0}
+    NULL_SPAN singleton without ever constructing a Span or a profiler
+    annotation."""
+    calls = {"n": 0, "ann": 0}
     orig_init = trace.Span.__init__
 
     def counting_init(self, *a, **kw):
@@ -79,19 +100,40 @@ def test_disabled_path_constructs_no_span(monkeypatch):
         orig_init(self, *a, **kw)
 
     monkeypatch.setattr(trace.Span, "__init__", counting_init)
+    _count_annotations(monkeypatch, calls)
     assert trace.current() is None
     for _ in range(100):
         with trace.span("hot", attr=1) as sp:
-            sp.set(more=2)
+            sp.measured(0.0, 1.0).set(more=2)
         trace.add_span("hot2", 0.0, 1.0, rows=5)
-    assert calls["n"] == 0
+    assert calls == {"n": 0, "ann": 0}
     assert trace.span("x") is trace.NULL_SPAN
-    # and the same shim proves the enabled path does construct spans
+    # and the same shims prove the enabled path constructs both, once
     tr = trace.Tracer()
     with trace.tracing(tr):
         with trace.span("on"):
             pass
-    assert calls["n"] == 1 and len(tr) == 1
+        trace.add_span("retro", 0.0, 1.0)
+    assert calls == {"n": 1, "ann": 1} and len(tr) == 2
+
+
+def test_untraced_spgemm_constructs_no_annotation(monkeypatch):
+    calls = {"ann": 0}
+    _count_annotations(monkeypatch, calls)
+    a = formats.random_uniform_csr(11, 48, 40, 4.0)
+    b = formats.random_uniform_csr(12, 40, 52, 4.0)
+    for executor in ("pipelined", "threaded", "serial"):
+        ocean_spgemm(a, b, cache=False, executor=executor)
+    assert calls["ann"] == 0
+
+
+def test_measured_span_records_the_callers_measurement():
+    tr = trace.Tracer()
+    with trace.tracing(tr):
+        with trace.span("stage") as sp:
+            sp.measured(tr.epoch + 1.0, 0.25)
+    ev = tr.events()[0]
+    assert (ev["t0"], ev["dur"]) == (tr.epoch + 1.0, 0.25)
 
 
 def test_threaded_spans_keep_independent_stacks():
@@ -119,73 +161,124 @@ def test_threaded_spans_keep_independent_stacks():
 
 
 # ---------------------------------------------------------------------------
-# Perfetto export
+# spans on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def test_chrome_trace_round_trip(tmp_path):
+def _profiled(tmp_path, fn):
+    """Run ``fn`` with a Tracer installed under ``jax.profiler``; returns
+    (fn's result, the tracer, the host plane's lines)."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
     tr = trace.Tracer()
-    with trace.tracing(tr):
-        with trace.span("outer"):
-            with trace.span("inner", rows=2):
-                pass
-        tr.add_span("lane2", tr.epoch, 0.5, tid=7, thread="other")
-    path = tmp_path / "trace.json"
-    doc = write_chrome_trace(tr, str(path))
-    # the written file re-parses and validates
-    reparsed = validate_chrome_trace(path.read_text())
-    assert reparsed == json.loads(json.dumps(doc))
-    evs = doc["traceEvents"]
-    assert {e["name"] for e in evs} == {"outer", "inner", "lane2"}
-    assert all(e["ph"] == "X" and e["dur"] >= 0.0 and e["ts"] >= 0.0
-               for e in evs)
-    by_name = {e["name"]: e for e in evs}
-    assert by_name["inner"]["args"] == {"rows": 2, "parent": "outer"}
-    assert by_name["lane2"]["tid"] == 7
-    assert len({e["tid"] for e in evs}) == 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.tracing(tr):
+            out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path[0])
+    lines = [ln for plane in pd.planes if plane.name.startswith("/host:")
+             for ln in plane.lines]
+    return out, tr, lines
 
 
-def test_validator_rejects_malformed_traces():
-    with pytest.raises(ValueError, match="traceEvents"):
-        validate_chrome_trace(json.dumps({"traceEvents": []}))
-    base = {"name": "a", "ph": "X", "ts": 0.0, "dur": 5.0,
-            "pid": 0, "tid": 1}
-    with pytest.raises(ValueError, match="missing"):
-        validate_chrome_trace(json.dumps(
-            {"traceEvents": [{k: v for k, v in base.items()
-                              if k != "dur"}]}))
-    with pytest.raises(ValueError, match="negative"):
-        validate_chrome_trace(json.dumps(
-            {"traceEvents": [dict(base, dur=-1.0)]}))
-    # partial overlap on one lane is not proper nesting
-    bad = [dict(base), dict(base, name="b", ts=3.0, dur=5.0)]
-    with pytest.raises(ValueError, match="overlaps"):
-        validate_chrome_trace(json.dumps({"traceEvents": bad}))
-    # while true nesting on one lane passes
-    ok = [dict(base), dict(base, name="b", ts=1.0, dur=2.0)]
-    validate_chrome_trace(json.dumps({"traceEvents": ok}))
+def _seconds(lines, name):
+    return sum(e.duration_ns for ln in lines for e in ln.events
+               if e.name == name) * 1e-9
 
 
-def test_traced_spgemm_exports_well_formed(tmp_path):
-    """End-to-end: one traced multiply covers the pipeline span set and
-    the exported trace validates; the same run untraced records nothing."""
-    a = formats.random_uniform_csr(11, 48, 40, 4.0)
-    b = formats.random_uniform_csr(12, 40, 52, 4.0)
-    c_ref, _ = ocean_spgemm(a, b, cache=False)
-    tr = trace.Tracer()
-    with trace.tracing(tr):
-        c, rep = ocean_spgemm(a, b, cache=False, executor="threaded")
-    assert np.array_equal(np.asarray(c.indptr), np.asarray(c_ref.indptr))
-    names = set(tr.names())
-    assert {"plan.analysis", "plan.prediction", "plan.binning",
-            "exec.dispatch", "exec.collect", "exec.compact"} <= names
-    path = tmp_path / "spgemm_trace.json"
-    doc = write_chrome_trace(tr, str(path))
-    validate_chrome_trace(path.read_text())
-    assert len(doc["traceEvents"]) == len(tr)
-    # tracing uninstalled: the same call records nothing anywhere
-    n_before = len(tr)
-    ocean_spgemm(a, b, cache=False, executor="threaded")
-    assert len(tr) == n_before and trace.current() is None
+def test_traced_spgemm_lands_on_the_profilers_host_plane(tmp_path):
+    """Each stage's annotation on the profiler's host plane brackets the
+    measurement its stage_seconds holds, within 1 ms; the tracer records
+    that very measurement."""
+    a = formats.powerlaw_csr(45, 256, 256, 8.0)
+    c_ref, _ = ocean_spgemm(a, a, cache=False)
+    (c, rep), tr, lines = _profiled(
+        tmp_path, lambda: ocean_spgemm(a, a, cache=False))
+    assert np.array_equal(np.asarray(c.indices), np.asarray(c_ref.indices))
+    for name, stage in [("plan.prediction", "prediction"),
+                        ("exec.dispatch", "dispatch"),
+                        ("exec.collect", "collect")]:
+        on_plane = _seconds(lines, name)
+        assert on_plane > 0.0, name
+        assert on_plane == pytest.approx(rep.stage_seconds[stage],
+                                         abs=1e-3), name
+        recorded = sum(e["dur"] for e in tr.events() if e["name"] == name)
+        assert recorded == pytest.approx(rep.stage_seconds[stage],
+                                         rel=1e-9, abs=1e-12), name
+    compact = [e["dur"] for e in tr.events() if e["name"] == "exec.compact"]
+    assert len(compact) == 1
+    assert _seconds(lines, "exec.compact") == pytest.approx(compact[0],
+                                                            abs=1e-3)
+    for name in ("plan.analysis", "plan.binning", "analysis.wave1",
+                 "analysis.wave2", "exec.merge", "exec.report"):
+        assert _seconds(lines, name) > 0.0, name
+
+
+def test_merge_worker_spans_sit_on_the_workers_thread_line(tmp_path):
+    a = formats.skewed_rows_csr(44, 400, 400, 5.0)
+    _, tr, lines = _profiled(
+        tmp_path, lambda: ocean_spgemm(a, a, cache=False,
+                                       executor="threaded"))
+    names = [{e.name for e in ln.events} for ln in lines]
+    worker = [n for n in names if "exec.merge_worker" in n]
+    assert len(worker) == 1
+    assert not worker[0] & {"exec.collect", "exec.dispatch",
+                            "plan.analysis"}
+    main = [n for n in names if "exec.collect" in n]
+    assert len(main) == 1 and "exec.merge_worker" not in main[0]
+    # the tracer's own records: the worker's thread, no parent there
+    evs = [e for e in tr.events() if e["name"] == "exec.merge_worker"]
+    assert evs and {e["thread"] for e in evs} == {"ocean-merge-worker"}
+    assert all(e["parent"] is None for e in evs)
+
+
+# ---------------------------------------------------------------------------
+# host<->device copy counts
+# ---------------------------------------------------------------------------
+
+def test_copy_helpers_count_what_crosses():
+    copies = dispatch.new_copy_bytes()
+    dev = dispatch.to_device(np.arange(6, dtype=np.int32), copies)
+    assert copies == {"d2h": 0, "h2d": 24}
+    # a device array moves between devices, if at all: nothing crosses
+    dispatch.to_device(dev, copies)
+    # int64 lands as int32 while x64 is off
+    dispatch.to_device(np.arange(3, dtype=np.int64), copies)
+    assert copies == {"d2h": 0, "h2d": 36}
+    host = dispatch.to_host(dev, copies)
+    assert host.tolist() == list(range(6)) and copies["d2h"] == 24
+    dispatch.to_host(host, copies)
+    assert copies["d2h"] == 24
+
+
+def test_copy_bytes_of_a_small_product_match_a_hand_count():
+    # A = B = the 8 x 8 identity: one product a row, so the selector takes
+    # the upper bound and every row goes to the ESC bin
+    a = formats.csr_from_arrays(np.arange(9), np.arange(8),
+                                np.ones(8, np.float32), (8, 8))
+    c, rep = ocean_spgemm(a, a, cache=False)
+    assert rep.workflow == "upper_bound" and rep.bins.get("esc") == 8
+    # planning: wave 1 uploads A and B as blocks padded to 64 rows and
+    # 256 entries ((65 + 256) int32 each); wave 2 reads back products and
+    # output column bounds, 64 int32 each
+    plan_h2d, plan_d2h = 2 * (65 + 256) * 4, 3 * 64 * 4
+    # execution: A's values down (8 f32); the ESC bin's sub-CSR up (9 + 8
+    # int32, 8 f32); its count (1 int32), row pointers (9 int32) and one
+    # ELL column of indices and values (8 + 8) down; C up (9 + 8 int32,
+    # 8 f32); C's row pointers down for the accuracy telemetry (9 int32)
+    exec_h2d = (9 + 8 + 8) * 4 + (9 + 8 + 8) * 4
+    exec_d2h = (8 + 1 + 9 + 16 + 9) * 4
+    assert rep.copy_bytes == {"d2h": plan_d2h + exec_d2h,
+                              "h2d": plan_h2d + exec_h2d}
+    # a plan-cache hit plans nothing: only the execution's copies
+    cache = PlanCache()
+    ocean_spgemm(a, a, cache=cache)
+    _, hit = ocean_spgemm(a, a, cache=cache)
+    assert hit.plan_cache_hit
+    assert hit.copy_bytes == {"d2h": exec_d2h, "h2d": exec_h2d}
 
 
 # ---------------------------------------------------------------------------
