@@ -1,11 +1,12 @@
 """ESC (Expand-Sort-Compact) accumulation and the exact symbolic pass.
 
 On TPU, sorting is a first-class XLA primitive, so ESC maps almost verbatim
-from the paper (§2.2/§3.3): expansion is a vectorized gather driven by a
-``cumsum``+``searchsorted`` product enumeration; sorting is one stable
-two-key ``lax.sort`` on (row, col) — no packed ``row*n + col`` key, so
-nothing wraps however wide C is while x64 stays disabled; compaction is
-a segmented sum.
+from the paper (§2.2/§3.3): expansion numbers the products by prefix
+scans (each per-A-entry quantity is scattered at its products' first
+slot and spread by a ``cumsum``) and reads B with one gather; sorting is
+one stable two-key ``lax.sort`` on (row, col) — no packed ``row*n + col``
+key, so nothing wraps however wide C is while x64 stays disabled;
+compaction is a segmented sum.
 
 The same machinery with indices only implements the *exact symbolic pass*
 (the two-pass baseline Ocean replaces), and serves as the overflow-fallback
@@ -21,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .formats import CSR, PAD_COL
-from .hll import row_ids_from_indptr
 
 
 class EscOverflowError(ValueError):
@@ -47,13 +47,36 @@ def _b_row_nnz(b_indptr):
     return b_indptr[1:] - b_indptr[:-1]
 
 
+def _spread(per_seg, starts, size: int):
+    """``per_seg[s]`` at every position of segment ``s`` of ``size``.
+
+    Segment ``s`` begins at ``starts[s]`` (non-decreasing, ``starts[0]``
+    0) and runs to the next start; empty segments share a start with the
+    one after them, and starts at or past ``size`` are dropped. Each
+    segment's difference from the one before is scattered at its start
+    and a prefix sum adds them back up, so position ``p`` reads the last
+    segment starting at or before ``p``. Integer sums wrap, so the
+    telescoped value is exact for any integer ``per_seg``.
+    """
+    steps = per_seg - jnp.concatenate([jnp.zeros((1,), per_seg.dtype),
+                                       per_seg[:-1]])
+    heads = jnp.zeros((size,), per_seg.dtype).at[starts].add(steps,
+                                                             mode="drop")
+    return jax.lax.cumsum(heads)
+
+
 @partial(jax.jit, static_argnames=("p_cap", "num_rows_a", "with_values"))
 def expand(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
            *, p_cap: int, num_rows_a: int, with_values: bool = True) -> Expanded:
     """Enumerate all intermediate products of C = A @ B into flat arrays.
 
-    Product ``p`` maps to A-nonzero ``j`` (via searchsorted over per-nnz
-    product offsets) and within-B-row position ``t``.
+    A-entry ``s`` (a *slot*) owns the contiguous products
+    ``offsets[s] .. offsets[s+1]-1``, in row-major order. Per-slot and
+    per-row quantities reach their products by :func:`_spread`, an
+    O(cap_a) scatter plus one O(p_cap) prefix sum: the product's row, the
+    shift ``b_indptr[k] - offsets[s]`` that turns product ``p`` into its
+    position in B, and A's value (as its bit pattern, so exactly). The
+    only p_cap-wide gathers read B at that position.
     """
     cap_a = a_indices.shape[0]
     nnz_a = a_indptr[-1]
@@ -63,22 +86,28 @@ def expand(a_indptr, a_indices, a_values, b_indptr, b_indices, b_values,
     k_of_slot = jnp.clip(a_indices, 0, b_len.shape[0] - 1)
     len_of_slot = jnp.where(slot_valid, b_len[k_of_slot], 0)
     offsets = jnp.concatenate([jnp.zeros((1,), len_of_slot.dtype),
-                               jnp.cumsum(len_of_slot)])
-    total = offsets[-1].astype(jnp.int32)
+                               jnp.cumsum(len_of_slot)]).astype(jnp.int32)
+    total = offsets[-1]
+    starts = offsets[:-1]
 
     p = jnp.arange(p_cap, dtype=jnp.int32)
-    j = jnp.searchsorted(offsets, p, side="right").astype(jnp.int32) - 1
-    j = jnp.clip(j, 0, cap_a - 1)
-    t = p - offsets[j].astype(jnp.int32)
     valid = p < total
 
-    a_row = jnp.clip(row_ids_from_indptr(a_indptr, cap_a), 0, num_rows_a - 1)
-    rows = jnp.where(valid, a_row[j], num_rows_a)  # pads -> sentinel row
-    k = k_of_slot[j]
-    b_pos = jnp.clip(b_indptr[k].astype(jnp.int32) + t, 0, b_indices.shape[0] - 1)
+    m = a_indptr.shape[0] - 1
+    row = _spread(jnp.arange(m, dtype=jnp.int32),
+                  offsets[a_indptr[:-1]], p_cap)
+    rows = jnp.where(valid, jnp.clip(row, 0, num_rows_a - 1),
+                     num_rows_a)  # pads -> sentinel row
+    shift = b_indptr[k_of_slot].astype(jnp.int32) - starts
+    b_pos = jnp.clip(p + _spread(shift, starts, p_cap), 0,
+                     b_indices.shape[0] - 1)
     cols = jnp.where(valid, b_indices[b_pos], PAD_COL)
     if with_values:
-        vals = jnp.where(valid, a_values[j] * b_values[b_pos], 0)
+        bits = jnp.dtype(f"int{8 * a_values.dtype.itemsize}")
+        a_val = jax.lax.bitcast_convert_type(
+            _spread(jax.lax.bitcast_convert_type(a_values, bits), starts,
+                    p_cap), a_values.dtype)
+        vals = jnp.where(valid, a_val * b_values[b_pos], 0)
     else:
         vals = jnp.zeros((p_cap,), jnp.float32)
     return Expanded(rows, cols, vals, valid, total)

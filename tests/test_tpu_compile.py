@@ -4,8 +4,10 @@ Nothing runs: each test lowers a kernel at the widths ``chip_smoke.py``
 produces (2**18-row matrices) for a described ``v5e:2x2`` topology and
 compiles it with the TPU compiler, which refuses what the chip cannot
 run (unaligned blocks, unsupported primitives, too much SMEM or VMEM)
-where interpret mode accepts it. The topology is described inside a
-fixture, never at import, and every such test lives in this one file.
+where interpret mode accepts it. The XLA-only ESC passes compile at the
+HPCG benchmark cell's shapes, and must come out free of loops. The
+topology is described inside a fixture, never at import, and every such
+test lives in this one file.
 """
 import functools
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import esc
 from repro.core.binning import hash_spill_of
 from repro.kernels import hll as khll
 from repro.kernels import spgemm_dense as kdense
@@ -99,3 +102,27 @@ def test_spgemm_hash_bin_compiles(one_chip, rows, ell, table, tile, f_chunk):
                            f_chunk=f_chunk)
     _compile(fn, one_chip, *_bin_shapes(rows, ell), ((NNZ_B,), jnp.int32),
              ((NNZ_B,), jnp.float32))
+
+
+# The HPCG cell's A (= B): rows, nnz, and the product slots of A·A
+HPCG_ROWS, HPCG_NNZ, HPCG_P_CAP = 46656, 1191016, 2**25
+
+
+@pytest.mark.parametrize("pass_", ["symbolic_exact", "esc_spgemm"])
+def test_esc_pass_compiles_without_loops(one_chip, pass_):
+    """Products are enumerated by scans: a per-product binary search
+    (``searchsorted``) would compile to a ``while`` over the product axis."""
+    m, nnz = HPCG_ROWS, HPCG_NNZ
+    csr = [((m + 1,), jnp.int32), ((nnz,), jnp.int32),
+           ((nnz,), jnp.float32)]
+    if pass_ == "symbolic_exact":
+        fn = functools.partial(esc.symbolic_exact, p_cap=HPCG_P_CAP,
+                               num_rows_a=m)
+        shapes = csr[:2] * 2
+    else:
+        fn = functools.partial(esc.esc_spgemm, p_cap=HPCG_P_CAP,
+                               out_cap=HPCG_P_CAP, num_rows_a=m)
+        shapes = csr * 2
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "while" not in text
