@@ -31,9 +31,10 @@ Adding to the benchmark takes new files and new entries in
 
 * a cell: an entry of ``workloads`` naming a configuration and a traffic;
 * a configuration: ``bench/configs/<name>.json`` (``generator``, its
-  ``params``, ``source``, ``reduced``, ``assumed``, and the comparison's
-  ``limits``) and, for a new family of matrices, a generator module
-  ``bench/generators/<generator>.py`` with ``build(params)``;
+  ``params``, ``small``: the ``params`` of a size the CPU tests run,
+  ``source``, ``reduced``, ``assumed``, ``reckoned`` sizes, and the
+  comparison's ``limits``) and, for a new family of matrices, a generator
+  module ``bench/generators/<generator>.py`` with ``build(params)``;
 * a traffic mix: ``bench/traffic/<name>.json``: ``operands`` (``a_a`` for
   A @ A, ``a_at`` for A @ A^T), ``arrival`` (``{"kind": "closed"}``, or
   ``{"kind": "open", "rate_per_s": r}``), ``plan_cache`` (``off`` or
@@ -297,7 +298,7 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *,
                    for m in cell.per_layer]
         breakdown = {"device_ops": trace_reduce.top_ops(tr),
                      "idle_gaps": trace_reduce.idle_gaps(
-                         tr, _program_spans(tracer, tr, calls))}
+                         tr, _named_spans(tr, tracer))}
         shutil.rmtree(trace_dir, ignore_errors=True)
     else:
         win = Window(calls=calls, window_s=window_s, setup_s=setup_s,
@@ -367,26 +368,12 @@ def _passes(comp, limits) -> bool:
             <= limits["value_err_over_f32_bound"])
 
 
-def _program_spans(tracer, tr, calls):
-    """The program's own spans (``repro.obs.trace``, on the host's
-    ``perf_counter``) moved onto the profiler's clock, call by call, by
-    the offset between each call's start and its ``bench.call``
-    annotation."""
-    from bench.trace_reduce import Event
-    starts = [c.start for c in calls]
-    anns = sorted((e for e in tr.host_spans if e.name == "bench.call"),
-                  key=lambda e: e.start_ns)
-    if tracer is None or len(anns) != len(starts):
-        return []
-    spans = []
-    bounds = starts[1:] + [float("inf")]
-    for ev in tracer.events():
-        k = next((j for j, b in enumerate(bounds) if ev["t0"] < b), None)
-        if k is None:
-            continue
-        off = anns[k].start_ns - starts[k] * 1e9
-        spans.append(Event(ev["name"], ev["t0"] * 1e9 + off, ev["dur"] * 1e9))
-    return spans + anns
+def _named_spans(tr, tracer):
+    """The trace's host spans that the program's tracer or the benchmark
+    opened, on the trace's clock: what the host was doing, without the
+    runtime's own events."""
+    names = {"bench.window", "bench.call", *tracer.names()}
+    return [e for e in tr.host_spans if e.name in names]
 
 
 def main(argv=None) -> int:

@@ -13,11 +13,6 @@ import pytest
 from bench import cells, control, run
 from repro.core import workflow
 
-SMALL = {
-    "hpcg_27pt_n36": {"nx": 6, "ny": 5, "nz": 4},
-    "kron_g500_s13": {"scale": 7, "edge_factor": 16, "a": 0.57, "b": 0.19,
-                      "c": 0.19, "graph_seed": 1},
-}
 CELLS = [w["name"] for w in cells.load_benchmark()["workloads"]]
 # traffic that no cell runs yet, driven through the same window
 TRAFFIC = {
@@ -28,8 +23,10 @@ TRAFFIC = {
 
 
 def _small(name, traffic=None, **over):
+    """The cell at its configuration's ``small`` size, on other traffic or
+    with traffic keys changed where asked."""
     cell = cells.resolve(name)
-    cell.config = dict(cell.config, params=SMALL[cell.config_name])
+    cell.config = dict(cell.config, params=cell.config["small"])
     if traffic is not None:
         with open(cells.BENCH_DIR / "traffic" / f"{traffic}.json") as f:
             cell.traffic = json.load(f)
@@ -102,10 +99,13 @@ def test_bfloat16_control_is_not_correct(name):
         r["checks"]["value_err_over_f32_bound"]["limit"]
 
 
-@pytest.mark.parametrize("fault", [_altered_value, _dropped_entry,
-                                   _half_rows_left_out])
-def test_planted_fault_is_not_correct(fault):
-    r = _run(_small(CELLS[0]), fault)
+@pytest.mark.parametrize("name,fault", [
+    pytest.param(n, f, id=f.__name__ if n == CELLS[0]
+                 else f"{n}-{f.__name__}")
+    for n in CELLS for f in (_altered_value, _dropped_entry,
+                             _half_rows_left_out)])
+def test_planted_fault_is_not_correct(name, fault):
+    r = _run(_small(name), fault)
     assert not r["correct"]
     assert r["failed"] == r["attempted"]
 

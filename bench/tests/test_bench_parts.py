@@ -163,9 +163,10 @@ def test_transpose_gathers_the_values():
     assert np.array_equal(m.values[take], t.values)
 
 
-@pytest.mark.parametrize("config", ["hpcg_27pt_n36", "kron_g500_s13"])
-def test_generators_give_the_reckoned_sizes(config):
-    with open(cells.BENCH_DIR / "configs" / f"{config}.json") as f:
+@pytest.mark.parametrize("entry", cells.load_benchmark()["configs"],
+                         ids=lambda c: c["name"])
+def test_generators_give_the_reckoned_sizes(entry):
+    with open(cells.ROOT / entry["file"]) as f:
         conf = json.load(f)
     g = cells.load_generator(conf)
     m = g.matrix(np.random.default_rng(7))
@@ -205,6 +206,9 @@ def test_every_workload_resolves_its_parts_by_name(name):
     cell = cells.resolve(name)
     assert cell.config_name == name.split(".")[0]
     assert cells.load_generator(cell.config) is not None
+    assert "small" in cell.config
+    assert cells.load_generator(dict(cell.config,
+                                     params=cell.config["small"])) is not None
     assert cell.traffic["plan_cache"] in ("off", "private")
     assert cell.traffic["operands"] in ("a_a", "a_at")
     assert set(cell.config["limits"]) == {"rows_wrong",
