@@ -192,20 +192,22 @@ def _esc_to_slab(res, rows: np.ndarray, num_rows: int,
                  out_cap: int, copies: Dict[str, int]) -> Tuple[_Slab, int]:
     """Convert an ESCResult over a row subset, on the device, into a slab
     on the host: the count and the row pointers come down, the rows are
-    laid out as ELL on the device, and the ELL blocks come down."""
-    nnz = esc_mod.ensure_esc_capacity(to_host(res.nnz, copies), out_cap,
-                                      where="ESC shard")
-    # shape-bucketed ESC shards carry inert pad rows past num_rows (zero
-    # counts by construction); slice them off before slab assembly
-    ptr = to_host(res.indptr, copies).astype(np.int64)
-    counts = (ptr[1:] - ptr[:-1])[:num_rows]
-    width = int(counts.max()) if len(counts) else 1
-    width = max(width, 1)
-    ell_i, ell_v = csr_rows_to_ell(res.indptr, res.indices, res.values,
-                                   num_rows=num_rows, ell_width=width,
-                                   pad_index=int(PAD_COL))
-    return _Slab(rows, to_host(ell_i, copies), to_host(ell_v, copies),
-                 counts), nnz
+    laid out as ELL on the device, and the ELL blocks come down. The
+    ``exec.esc`` span covers all of it, the wait for the pass included."""
+    with trace.span("exec.esc"):
+        nnz = esc_mod.ensure_esc_capacity(to_host(res.nnz, copies), out_cap,
+                                          where="ESC shard")
+        # shape-bucketed ESC shards carry inert pad rows past num_rows
+        # (zero counts by construction); slice them off before slab assembly
+        ptr = to_host(res.indptr, copies).astype(np.int64)
+        counts = (ptr[1:] - ptr[:-1])[:num_rows]
+        width = int(counts.max()) if len(counts) else 1
+        width = max(width, 1)
+        ell_i, ell_v = csr_rows_to_ell(res.indptr, res.indices, res.values,
+                                       num_rows=num_rows, ell_width=width,
+                                       pad_index=int(PAD_COL))
+        return _Slab(rows, to_host(ell_i, copies), to_host(ell_v, copies),
+                     counts), nnz
 
 
 def _upload_csr(indptr: np.ndarray, indices: np.ndarray, values: np.ndarray,
@@ -423,6 +425,14 @@ class _MergeState:
         # the feed-forward sizes graph chains record (see OceanReport)
         self.raw_counts = (np.zeros(m_rows, np.int64)
                            if post is not None else None)
+        # the call's ESC passes, the ESC bin's and the overflow
+        # fallback's: products expanded and product slots (p_cap) allocated
+        self.esc_products = 0
+        self.esc_slots = 0
+
+    def count_esc(self, products: int, slots: int) -> None:
+        self.esc_products += products
+        self.esc_slots += slots
 
     def _admit(self, order: int, slab: _Slab) -> None:
         if self.post is not None:
@@ -442,6 +452,10 @@ class _MergeState:
             # so the fed-forward sizes are exact on every path.
             self.raw_counts[slab.rows] = slab.nnz
         kind, exec_ = it.tag
+        if kind == "esc":
+            # an ESC bin's row costs are its rows' exact product counts
+            ex, _ = exec_
+            self.count_esc(int(ex.cost.sum()), ex.p_cap)
         if kind in ("dense", "hash"):  # ESC caps are upper bounds
             over = slab.nnz > slab.cols.shape[1]
             if over.any():
@@ -519,12 +533,14 @@ def _run_overflow_fallback(state: _MergeState, products: np.ndarray,
         new_ptr, src = flat_gather_index(to_host(a.indptr, copies), rows)
         sub = _upload_csr(new_ptr, to_host(a.indices, copies)[src],
                           a_values[src], (len(rows), a.n), copies)
-        p_cap = pow2_at_least(int(products[rows].sum()), floor=64)
+        n_products = int(products[rows].sum())
+        p_cap = pow2_at_least(n_products, floor=64)
         res = esc_mod.esc_spgemm(
             sub.indptr, sub.indices, sub.values, b.indptr, b.indices,
             b.values, p_cap=p_cap, out_cap=p_cap, num_rows_a=sub.m)
         slab, _ = _esc_to_slab(res, rows, sub.m, p_cap, copies)
         state.add_fallback(slab)
+        state.count_esc(n_products, p_cap)
         dt = time.perf_counter() - t0
         sp.measured(t0, dt).set(rows=len(rows))
     return len(rows), dt
@@ -561,8 +577,7 @@ def _collect_serial(items: List[Launch], plan: ExecutionPlan, a: CSR,
                                   a_values.dtype, copies)
         stage["postprocess"] = time.perf_counter() - t0
         sp.measured(t0, stage["postprocess"])
-    return (c, total, n_overflow, 0.0, 0.0, state.raw_counts,
-            state.overflow_causes)
+    return c, total, n_overflow, 0.0, state
 
 
 def _finish_merge(state: _MergeState, plan: ExecutionPlan, a: CSR, b: CSR,
@@ -625,9 +640,7 @@ def _collect_pipelined(items: List[Launch], plan: ExecutionPlan, a: CSR,
     stage["dispatch"] = dispatch_s
     stage["collect"] = collect_s
     stage["merge"] = merge_s
-    frac = overlap_s / merge_s if merge_s > 0.0 else 0.0
-    return (c, total, n_overflow, overlap_s, frac, state.raw_counts,
-            state.overflow_causes)
+    return c, total, n_overflow, overlap_s, state
 
 
 def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
@@ -704,9 +717,7 @@ def _collect_threaded(items: List[Launch], plan: ExecutionPlan, a: CSR,
     stage["dispatch"] = dispatch_s
     stage["collect"] = collect_s
     stage["merge"] = merge_s
-    frac = overlap_s / merge_s if merge_s > 0.0 else 0.0
-    return (c, total, n_overflow, overlap_s, frac, state.raw_counts,
-            state.overflow_causes)
+    return c, total, n_overflow, overlap_s, state
 
 
 _COLLECT_OF = {PIPELINED: _collect_pipelined, THREADED: _collect_threaded,
@@ -746,8 +757,9 @@ def _execute(plan: ExecutionPlan, shards: List[_ShardWork], a: CSR, b: CSR,
         sp.measured(t0, dispatch_s).set(launches=len(items))
 
     collect = _COLLECT_OF[mode]
-    c, total, n_overflow, overlap_s, _frac, raw_counts, causes = collect(
+    c, total, n_overflow, overlap_s, state = collect(
         items, plan, a, b, a_values, stage, dispatch_s, post, copies)
+    raw_counts, causes = state.raw_counts, state.overflow_causes
     with trace.span("exec.report"):
         # overlap is merge work by definition; clamp so the derived
         # merge_overlap_frac view stays in [0, 1] even under clock jitter
@@ -780,7 +792,8 @@ def _execute(plan: ExecutionPlan, shards: List[_ShardWork], a: CSR, b: CSR,
             wave2_overlap_seconds=plan.wave2_overlap_seconds,
             wave2_overlapped=plan.wave2_overlapped,
             estimation_accuracy=accuracy, decision=plan.decision,
-            copy_bytes=copies)
+            copy_bytes=copies, esc_products=state.esc_products,
+            esc_slots=state.esc_slots)
     return c, report
 
 

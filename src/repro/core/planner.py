@@ -109,6 +109,10 @@ class OceanReport:
     # (core.dispatch.to_host / to_device count them)
     copy_bytes: Dict[str, int] = dataclasses.field(
         default_factory=new_copy_bytes)
+    # the call's ESC passes, the ESC bin's and the overflow fallback's:
+    # products expanded, and the product slots (p_cap) allocated for them
+    esc_products: int = 0
+    esc_slots: int = 0
 
     @property
     def total_seconds(self) -> float:
